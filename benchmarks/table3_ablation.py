@@ -61,6 +61,9 @@ for pipeline, lowering in [(False, False), (False, True), (True, False),
 
 def run_real(csv=print) -> dict:
     env = dict(os.environ)
+    # two forced host devices: the child runs on the CPU, never on a chip
+    # the parent process (benchmarks/run.py) may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
     r = subprocess.run([sys.executable, "-c", _REAL_SCRIPT], env=env,

@@ -102,20 +102,17 @@ def audit_closure(fn, args: Sequence, *, target: str = "step",
 # ---------------------------------------------------------------------------
 
 def audit_hlo(hlo: str, *, pool_param: int, n_layers: int, k: int = 1,
-              target: str = "step", expect_donation: bool = True
-              ) -> List[AuditFinding]:
+              target: str = "step") -> List[AuditFinding]:
     """Audit one compiled module's text (donation, transfers, loops).
 
     ``pool_param`` is the FLATTENED entry-parameter number of the donated
     pool buffer (see :func:`flat_param_index`); ``k`` is the program's
-    decode-steps-per-dispatch.  ``expect_donation=False`` skips CPA02 —
-    the repo's ``kernels.ops.donate_argnums`` gate disables donation
-    wholesale on backends that cannot alias (CPU), and an alias that was
-    never requested cannot be "dropped".
+    decode-steps-per-dispatch.  Every fused step donates its pool on every
+    backend, so a pool parameter without an alias is always a finding.
     """
     findings = []
     donated = ha.donated_params(hlo)
-    if expect_donation and pool_param not in donated:
+    if pool_param not in donated:
         findings.append(AuditFinding(
             "CPA02", target,
             f"pool parameter {pool_param} is not input-output aliased "
@@ -164,17 +161,13 @@ def audit_fused_step(step, args: Sequence, *, n_layers: int, k: int = 1,
     """Full audit of one fused step object (``PagedFusedStep`` /
     ``MultiStepFusedStep``): closure trace + compiled-HLO structure.
 
-    CPA02 is checked exactly when the repo actually requested donation
-    for this backend (``kernels.ops.donate_argnums`` is the single gate
-    every fused step goes through)."""
-    from repro.kernels.ops import donate_argnums
-
+    Every fused step donates its pool on every backend, so CPA02 is
+    always checked."""
     findings = audit_closure(step._step.__wrapped__, args, target=target,
                              max_const_bytes=max_const_bytes)
     hlo = step._step.lower(*args).compile().as_text()
     findings += audit_hlo(hlo, pool_param=flat_param_index(args, pool_argnum),
-                          n_layers=n_layers, k=k, target=target,
-                          expect_donation=bool(donate_argnums(pool_argnum)))
+                          n_layers=n_layers, k=k, target=target)
     return findings
 
 

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.pools import PooledModel, transfer
-from repro.kernels.ops import donate_argnums as _donate
 
 
 class HostDrivenStep:
@@ -59,7 +58,7 @@ class HostDrivenStep:
         # execution placement follows the committed pool buffers: attention
         # stages run where kv_params live, FFN stages where the arena lives.
         self._embed = jax.jit(fns.embed)
-        self._attn = jax.jit(fns.attn_stage, donate_argnums=_donate(2))
+        self._attn = jax.jit(fns.attn_stage, donate_argnums=2)
         self._ffn = jax.jit(fns.ffn_stage)
         self._combine = jax.jit(fns.combine)
         self._logits = jax.jit(fns.logits)
@@ -332,7 +331,7 @@ class PagedFusedStep:
             out = postprocess(logits) if postprocess is not None else logits
             return out, pool
 
-        self._step = jax.jit(step, donate_argnums=_donate(4))
+        self._step = jax.jit(step, donate_argnums=4)
 
     def __call__(self, tokens, pool, page_tables, lengths
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -421,7 +420,7 @@ class MultiStepFusedStep:
                 jnp.arange(n_steps))
             return out, pool
 
-        self._step = jax.jit(step, donate_argnums=_donate(4))
+        self._step = jax.jit(step, donate_argnums=4)
 
     def __call__(self, tokens, pool, page_tables, lengths, steps_left,
                  eos_ids=None, key=None) -> Tuple[jax.Array, jax.Array]:

@@ -20,7 +20,7 @@ sharding strategy inside one SPMD program.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -126,7 +126,8 @@ def transfer(x: jax.Array, device) -> jax.Array:
     return jax.device_put(x, device)
 
 
-def build_pools(models: Dict[str, ModelConfig], params: Dict[str, Dict], *,
+def build_pools(models: Dict[str, ModelConfig],
+                params: Union[Dict[str, Dict], Callable[[str], Dict]], *,
                 kv_device=None, w_device=None, page_budget: int,
                 page_bytes: int = DEFAULT_PAGE_BYTES,
                 pool_dtype=jnp.bfloat16,
@@ -150,6 +151,10 @@ def build_pools(models: Dict[str, ModelConfig], params: Dict[str, Dict], *,
     of idle models.  ``activate_resident`` eagerly activates models in
     registration order until the budget is full — remaining models are
     activated on demand by the engine.
+
+    ``params`` maps a model name to its full param tree, or is a callable
+    that builds it: models are split one at a time, so with a callable
+    only one full tree is alive at once.
     """
     devs = jax.devices()
     kv_device = kv_device or devs[0]
@@ -158,8 +163,9 @@ def build_pools(models: Dict[str, ModelConfig], params: Dict[str, Dict], *,
                           page_bytes=page_bytes, pool_dtype=pool_dtype,
                           allocate_device_pool=allocate_device_pool)
     w_pool = WeightsPool(arena_device or w_device, slab_bytes=slab_bytes)
+    tree_of = params if callable(params) else params.__getitem__
     for name, cfg in models.items():
-        kv_tree, w_tree = split_exec.split_params(params[name], cfg)
+        kv_tree, w_tree = split_exec.split_params(tree_of(name), cfg)
         kv_pool.add_model(name, kv_tree)
         w_pool.add_model(name, cfg, w_tree)
     if allocate_device_arena is None:

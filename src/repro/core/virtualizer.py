@@ -52,7 +52,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.errors import PoolAccountingError, check
-from repro.kernels.ops import donate_argnums, paged_kv_write
+from repro.kernels.ops import paged_kv_write
 
 #: The single page-size constant shared by the virtualizer, the pools and
 #: the engine.  16 KiB balances internal fragmentation (half a page per
@@ -163,67 +163,32 @@ class RequestPages:
 
 
 
-_POOL_SCATTER = None
-_ROW_SCATTER = None
-_ROW_GATHER = None
+def _pack_write(pool, a, b, pages, slots, n_tokens, batch_index, mla):
+    a = a[batch_index, :n_tokens]
+    b = b[batch_index, :n_tokens]
+    kv = (jnp.concatenate([a, b], axis=-1) if mla
+          else jnp.stack([a, b], axis=1))
+    return paged_kv_write(pool, kv.reshape(n_tokens, -1), pages, slots)
 
 
-def _pool_scatter(pool, kv_flat, pages, slots):
-    """One donated-buffer scatter of ``n`` token rows into the flat pool.
+#: One donated-buffer scatter of ``n`` token rows into the flat pool.
+_pool_scatter = jax.jit(paged_kv_write, donate_argnums=0)
 
-    Jitted lazily so importing this module does not initialize the jax
-    backend (``donate_argnums`` needs to know it)."""
-    global _POOL_SCATTER
-    if _POOL_SCATTER is None:
-        _POOL_SCATTER = jax.jit(paged_kv_write,
-                                donate_argnums=donate_argnums(0))
-    return _POOL_SCATTER(pool, kv_flat, pages, slots)
+#: Slice one batch row out of a layer's ``(a, b)`` KV pair, pack it to
+#: token rows, and scatter — ONE jitted dispatch with the pool donated.
+#: The packing (slice to ``n_tokens``, MLA concat / GQA stack, flatten)
+#: used to run as eager ops before the scatter; on the prefill path that
+#: is several host dispatches PER LAYER, which is exactly the fixed
+#: per-turn cost a cache-hit suffix prefill is trying to shed.
+_pool_pair_scatter = jax.jit(_pack_write, static_argnames=(
+    "n_tokens", "batch_index", "mla"), donate_argnums=0)
 
+#: Scatter whole page rows (fault-in from the host swap tier).
+_pool_row_scatter = jax.jit(lambda p, i, r: p.at[i].set(r), donate_argnums=0)
 
-_PAIR_SCATTER = None
-
-
-def _pool_pair_scatter(pool, a, b, pages, slots, *, n_tokens, batch_index,
-                       mla):
-    """Slice one batch row out of a layer's ``(a, b)`` KV pair, pack it to
-    token rows, and scatter — ONE jitted dispatch with the pool donated.
-
-    The packing (slice to ``n_tokens``, MLA concat / GQA stack, flatten)
-    used to run as eager ops before the scatter; on the prefill path that
-    is several host dispatches PER LAYER, which is exactly the fixed
-    per-turn cost a cache-hit suffix prefill is trying to shed."""
-    global _PAIR_SCATTER
-    if _PAIR_SCATTER is None:
-        def _pack_write(pool, a, b, pages, slots, n_tokens, batch_index,
-                        mla):
-            a = a[batch_index, :n_tokens]
-            b = b[batch_index, :n_tokens]
-            kv = (jnp.concatenate([a, b], axis=-1) if mla
-                  else jnp.stack([a, b], axis=1))
-            return paged_kv_write(pool, kv.reshape(n_tokens, -1),
-                                  pages, slots)
-        _PAIR_SCATTER = jax.jit(_pack_write, static_argnums=(5, 6, 7),
-                                donate_argnums=donate_argnums(0))
-    return _PAIR_SCATTER(pool, a, b, pages, slots, n_tokens, batch_index,
-                         mla)
-
-
-def _pool_row_scatter(pool, ids, rows):
-    """Scatter whole page rows (fault-in from the host swap tier)."""
-    global _ROW_SCATTER
-    if _ROW_SCATTER is None:
-        _ROW_SCATTER = jax.jit(lambda p, i, r: p.at[i].set(r),
-                               donate_argnums=donate_argnums(0))
-    return _ROW_SCATTER(pool, ids, rows)
-
-
-def _pool_row_gather(pool, ids):
-    """Gather whole page rows: ONE compiled gather builds the compacted /
-    resized pool buffer (also used to read rows out for swap-out)."""
-    global _ROW_GATHER
-    if _ROW_GATHER is None:
-        _ROW_GATHER = jax.jit(lambda p, i: p[i])
-    return _ROW_GATHER(pool, ids)
+#: Gather whole page rows: ONE compiled gather builds the compacted /
+#: resized pool buffer (also used to read rows out for swap-out).
+_pool_row_gather = jax.jit(lambda p, i: p[i])
 
 
 class KVVirtualizer:
@@ -244,11 +209,10 @@ class KVVirtualizer:
         self.requests: Dict[int, RequestPages] = {}
         self.pool: Optional[jax.Array] = None
         if allocate_device_pool:
-            pool = jnp.zeros((page_budget, self.page_elems), dtype)
             # co-locate with the KV pool's attention params (``device`` is
-            # KVCachePool's device; None = jax default)
-            self.pool = jax.device_put(pool, device) if device is not None \
-                else pool
+            # KVCachePool's device; None = jax default), allocated there
+            self.pool = jnp.zeros((page_budget, self.page_elems), dtype,
+                                  device=device)
         # incremental device page-table cache: key -> {buf, revs, dev}
         self._batch_cache: Dict[tuple, dict] = {}
         self._rev_counter = 0
@@ -810,7 +774,7 @@ class KVVirtualizer:
         if new_budget > old_budget:
             if self.pool is not None:
                 pad = jnp.zeros((new_budget - old_budget, self.page_elems),
-                                self.pool.dtype)
+                                self.pool.dtype, device=self.pool.sharding)
                 self.pool = jnp.concatenate([self.pool, pad], axis=0)
             # new ids go to the FRONT of the (pop-from-the-end) free list,
             # so existing low ids keep being preferred — allocation order

@@ -7,14 +7,15 @@ out of ONE pre-allocated **arena** of fixed-size slabs, and "loading a
 model" is slot-table bookkeeping plus an async host->device upload — not a
 per-model ``device_put`` that scales with the colocation count.
 
-  * the arena is an untyped byte array ``[slot_budget, slab_bytes]``
-    (uint8): heterogeneous models — bf16 experts, f32 routers — share the
-    same physical slabs and are reconstructed bit-exactly by in-program
-    bitcasts, the weights analogue of the KV pool's untyped pages;
+  * the arena is an untyped array of 16-bit words ``[slot_budget,
+    slab_rows, 128]``: heterogeneous models — bf16 experts, f32 routers —
+    share the same physical slabs and are reconstructed bit-exactly by
+    in-program same-width bitcasts, the weights analogue of the KV pool's
+    untyped pages;
   * every model's FFN tree is decomposed into per-layer **slab units**:
     one unit per expert (``wg``/``wu``/``wd`` of one expert of one layer)
     plus one "rest" unit per layer (router, shared experts, or the whole
-    dense MLP).  A unit occupies ``ceil(unit_bytes / slab_bytes)`` slabs;
+    dense MLP).  A unit occupies ``ceil(unit_rows / slab_rows)`` slabs;
   * **slow path** (host, per activation): ``activate`` / ``evict`` move
     slab ids between the free list and per-model slot tables.  Mapping is
     ATOMIC — eviction candidates are planned first and the slab count is
@@ -22,7 +23,7 @@ per-model ``device_put`` that scales with the colocation count.
     (same rule as ``KVVirtualizer.register_request``);
   * **fast path** (device, per layer): ``ffn_stage`` gathers one layer's
     slab rows through the model's slot table (``ModelArenaView
-    .unpack_layer``) and bitcasts them back into expert/MLP weight
+    .unpack_layer``) and bitcasts their words back into expert/MLP weight
     tensors — weights are read through a table exactly like KV pages;
   * master copies stay HOST-resident (packed slab form), so eviction is
     free (weights are read-only) and re-activation re-uploads the same
@@ -48,14 +49,16 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.errors import check
-from repro.kernels.ops import donate_argnums
 from repro.models.moe import EXPERT_STACKED_LEAVES
 
 #: Slab granularity of the weights arena.  Weights move in whole-expert
 #: units (tens of MB at paper scale), so the slab is far coarser than the
-#: 16 KiB KV page: 1 MiB keeps per-expert internal fragmentation under a
-#: slab per unit while the slot table stays short.
-DEFAULT_SLAB_BYTES = 1 << 20
+#: 16 KiB KV page: 512 KiB keeps per-expert internal fragmentation under a
+#: slab per unit while the slot table stays short.  It is also the largest
+#: slab (2048 rows of the arena's 16-bit words) the TPU compiler gathers
+#: as one slice: it splits a larger one in halves and joins them through a
+#: layer-sized pad-and-add copy, which doubles the FFN stage's scratch.
+DEFAULT_SLAB_BYTES = 1 << 19
 
 
 class OutOfSlabsError(RuntimeError):
@@ -66,6 +69,23 @@ class OutOfSlabsError(RuntimeError):
 # Static layout: how one model's FFN tree maps onto slabs
 # ---------------------------------------------------------------------------
 
+#: Storage of the arena: 16-bit words in rows of ``ARENA_LANES``, so a
+#: slab is ``[slab_rows, ARENA_LANES]`` and the slabs of one unit stack
+#: along the ROW axis.  Unpacking then only splits and slices row axes and
+#: bitcasts words of the same width; it never folds rows into the minor
+#: axis or builds a minor axis of an itemsize.  (On a TPU either relayout
+#: pads or shuffles whole tiles: a byte-level unpack of one 64-expert
+#: layer took minutes to compile and gigabytes of scratch.)  A 16-bit
+#: leaf (bf16) is a bitcast of its words; a 32-bit leaf (the f32 router,
+#: f32 models) is stored as two word PLANES — every element's low half,
+#: then every high half, each padded to whole rows — joined by a shift
+#: and an or.
+ARENA_WORD = np.uint16
+ARENA_LANES = 128
+_WORD_BYTES = np.dtype(ARENA_WORD).itemsize
+_ROW_BYTES = ARENA_LANES * _WORD_BYTES
+
+
 @dataclass(frozen=True)
 class LeafSpec:
     """One weight tensor inside a slab unit."""
@@ -73,8 +93,16 @@ class LeafSpec:
     path: Tuple[str, ...]          # e.g. ("moe", "wg") / ("mlp", "wd")
     dtype: jnp.dtype
     shape: Tuple[int, ...]         # per-unit shape (no layer/expert axes)
-    offset: int                    # byte offset inside the unit
-    nbytes: int
+    offset: int                    # first row inside the unit
+    plane_rows: int                # rows of one plane
+
+    @property
+    def planes(self) -> int:
+        return self.dtype.itemsize // _WORD_BYTES
+
+    @property
+    def rows(self) -> int:
+        return self.planes * self.plane_rows
 
 
 @dataclass(frozen=True)
@@ -84,7 +112,7 @@ class UnitSpec:
     kind: str                      # "expert" | "rest"
     count: int                     # units of this kind per layer (E or 1)
     leaves: Tuple[LeafSpec, ...]
-    unit_bytes: int
+    unit_rows: int
     slabs_per_unit: int
     slab_offset: int               # first slab of this kind in a layer row
 
@@ -106,13 +134,19 @@ def _is_expert_leaf(path: Tuple[str, ...], cfg: ModelConfig) -> bool:
             and path[1] in EXPERT_STACKED_LEAVES)
 
 
+def _leaf_rows(n_elems: int, itemsize: int) -> int:
+    """Arena rows one leaf occupies: each plane padded to whole rows."""
+    return (itemsize // _WORD_BYTES) * math.ceil(n_elems / ARENA_LANES)
+
+
 def _build_specs(kind: str, leaves: Sequence[Tuple[Tuple[str, ...], np.ndarray]],
-                 count: int, per_unit_axes: int, slab_bytes: int,
+                 count: int, per_unit_axes: int, slab_rows: int,
                  slab_offset: int) -> Optional[UnitSpec]:
     """Lay ``leaves`` out back-to-back inside one unit.
 
     ``per_unit_axes`` is how many leading axes (layer, expert) to strip
-    from the stacked array shape to get the per-unit tensor shape.
+    from the stacked array shape to get the per-unit tensor shape.  Only
+    shapes and dtypes are read, so ``jax.ShapeDtypeStruct`` leaves work.
     """
     if not leaves:
         return None
@@ -120,20 +154,39 @@ def _build_specs(kind: str, leaves: Sequence[Tuple[Tuple[str, ...], np.ndarray]]
     for path, arr in leaves:
         shape = tuple(arr.shape[per_unit_axes:])
         dt = jnp.dtype(arr.dtype)
-        nbytes = int(np.prod(shape, dtype=np.int64)) * dt.itemsize if shape \
-            else dt.itemsize
-        specs.append(LeafSpec(path, dt, shape, off, nbytes))
-        off += nbytes
+        check(dt.itemsize in (_WORD_BYTES, 2 * _WORD_BYTES),
+              f"arena leaf {'/'.join(path)} has {dt}; only 16- and 32-bit "
+              f"weights are stored")
+        leaf = LeafSpec(path, dt, shape, off,
+                        math.ceil(math.prod(shape) / ARENA_LANES))
+        specs.append(leaf)
+        off += leaf.rows
     return UnitSpec(kind, count, tuple(specs), off,
-                    max(1, math.ceil(off / slab_bytes)), slab_offset)
+                    max(1, math.ceil(off / slab_rows)), slab_offset)
 
 
-def _bitcast_from_bytes(raw: jax.Array, dtype: jnp.dtype) -> jax.Array:
-    """uint8 [..., n*itemsize] -> dtype [..., n] (value-exact)."""
-    itemsize = jnp.dtype(dtype).itemsize
-    n = raw.shape[-1] // itemsize
-    arr = raw.reshape(raw.shape[:-1] + (n, itemsize))
-    return jax.lax.bitcast_convert_type(arr, dtype)
+def _from_words(raw: jax.Array, leaf: LeafSpec) -> jax.Array:
+    """[count, rows, ARENA_LANES] words -> leaf.dtype [count, *leaf.shape]
+    (value-exact)."""
+    if leaf.planes == 1:
+        val = jax.lax.bitcast_convert_type(raw, leaf.dtype)
+    else:
+        lo = raw[:, :leaf.plane_rows].astype(jnp.uint32)
+        hi = raw[:, leaf.plane_rows:].astype(jnp.uint32)
+        val = jax.lax.bitcast_convert_type(lo | (hi << 16), leaf.dtype)
+    n = math.prod(leaf.shape)
+    if n % ARENA_LANES:                 # only tiny leaves pad their last row
+        val = val.reshape(val.shape[0], -1)[:, :n]
+    return val.reshape((val.shape[0],) + leaf.shape)
+
+
+def _to_words(arr: np.ndarray, leaf: LeafSpec) -> np.ndarray:
+    """Host inverse of :func:`_from_words`: [..., n] -> [..., rows, LANES]."""
+    w = arr.view(ARENA_WORD).reshape(arr.shape[:-1] + (-1, leaf.planes))
+    w = np.moveaxis(w, -1, -2)                        # [..., planes, n]
+    pad = leaf.plane_rows * ARENA_LANES - w.shape[-1]
+    w = np.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, pad)])
+    return w.reshape(arr.shape[:-1] + (leaf.rows, ARENA_LANES))
 
 
 @dataclass
@@ -141,15 +194,16 @@ class ModelArenaView:
     """Static slab geometry of one model + the in-program unpacker.
 
     The layout is identical for every layer, so a model's residency is a
-    ``[n_layers, slabs_per_layer]`` slot table and ``unpack_layer`` is one
-    gather + static slicing/bitcasting compiled into the FFN stage.
+    ``[n_layers, slabs_per_layer]`` slot table and ``unpack_layer`` is a
+    gather per leaf + static row slicing/bitcasting compiled into the FFN
+    stage.
     """
 
     name: str
     n_layers: int
     units: Tuple[UnitSpec, ...]
     slabs_per_layer: int
-    slab_bytes: int
+    slab_rows: int
 
     @property
     def total_slabs(self) -> int:
@@ -158,27 +212,31 @@ class ModelArenaView:
     def unpack_layer(self, arena: jax.Array, row: jax.Array) -> Dict:
         """Rebuild one layer's FFN param tree from the arena.
 
-        ``arena``: [slot_budget, slab_bytes] uint8; ``row``:
-        [slabs_per_layer] int32 slab ids.  ONE gather for the whole layer,
-        then static slices + bitcasts per leaf — bit-for-bit the packed
-        host bytes.
+        ``arena``: [slot_budget, slab_rows, ARENA_LANES] ARENA_WORD;
+        ``row``: [slabs_per_layer] int32 slab ids.  Per leaf, ONE gather
+        of just the slabs its rows touch (in every unit of its kind), then
+        a static row slice and a same-width bitcast — bit-for-bit the
+        packed host words.  Gathering per leaf, not per layer, lets each
+        leaf's rows feed its consumer without a second layer-sized copy.
         """
-        rows = arena[row]                       # [slabs_per_layer, slab_bytes]
         out: Dict = {}
         for u in self.units:
-            chunk = jax.lax.slice_in_dim(
-                rows, u.slab_offset,
-                u.slab_offset + u.count * u.slabs_per_unit, axis=0)
-            chunk = chunk.reshape(u.count, u.slabs_per_unit * self.slab_bytes)
+            unit_base = (u.slab_offset
+                         + u.slabs_per_unit * np.arange(u.count)[:, None])
             for leaf in u.leaves:
-                raw = jax.lax.slice_in_dim(
-                    chunk, leaf.offset, leaf.offset + leaf.nbytes, axis=1)
-                val = _bitcast_from_bytes(raw, leaf.dtype)
+                s0 = leaf.offset // self.slab_rows
+                s1 = -(-(leaf.offset + leaf.rows) // self.slab_rows)
+                raw = arena[row[unit_base + np.arange(s0, s1)]]
+                raw = raw.reshape(u.count, (s1 - s0) * self.slab_rows,
+                                  ARENA_LANES)
+                start = leaf.offset - s0 * self.slab_rows
+                val = _from_words(jax.lax.slice_in_dim(
+                    raw, start, start + leaf.rows, axis=1), leaf)
                 # expert units keep their stacked [E, ...] axis even when
                 # E == 1 (apply_moe expects the init_moe layout); rest
                 # units are per-layer tensors with no unit axis
-                val = val.reshape(((u.count,) if u.kind == "expert" else ())
-                                  + leaf.shape)
+                if u.kind != "expert":
+                    val = val[0]
                 dst = out
                 for k in leaf.path[:-1]:
                     dst = dst.setdefault(k, {})
@@ -186,16 +244,18 @@ class ModelArenaView:
         return out
 
 
-def build_view_and_slabs(name: str, cfg: ModelConfig, w_tree: Dict, *,
-                         slab_bytes: int
-                         ) -> Tuple[ModelArenaView, np.ndarray]:
-    """Decompose a split FFN tree into (static view, packed host slabs).
+def build_view(name: str, cfg: ModelConfig, w_tree: Dict, *,
+               slab_bytes: int) -> ModelArenaView:
+    """Static slab geometry of a split FFN tree.
 
     ``w_tree`` is ``split_exec.split_params``' weights-pool half with
-    layer-stacked leaves (host numpy).  Returns the view plus the packed
-    master copy ``[n_layers, slabs_per_layer, slab_bytes]`` uint8 — the
-    HOST-resident source every (re-)upload scatters from.
+    layer-stacked leaves.  Only leaf shapes and dtypes are read, so a tree
+    from ``jax.eval_shape`` gives the geometry without any weights.
     """
+    check(slab_bytes % _ROW_BYTES == 0,
+          f"slab_bytes {slab_bytes} is not a whole number of "
+          f"{_ROW_BYTES}-byte arena rows")
+    slab_rows = slab_bytes // _ROW_BYTES
     layer_leaves = _leaf_paths(w_tree["layers"])
     n_layers = layer_leaves[0][1].shape[0]
     expert = [(p, a) for p, a in layer_leaves if _is_expert_leaf(p, cfg)]
@@ -203,29 +263,35 @@ def build_view_and_slabs(name: str, cfg: ModelConfig, w_tree: Dict, *,
 
     units: List[UnitSpec] = []
     off = 0
-    eu = _build_specs("expert", expert, cfg.n_experts, 2, slab_bytes, off)
+    eu = _build_specs("expert", expert, cfg.n_experts, 2, slab_rows, off)
     if eu is not None:
         units.append(eu)
         off += eu.count * eu.slabs_per_unit
-    ru = _build_specs("rest", rest, 1, 1, slab_bytes, off)
+    ru = _build_specs("rest", rest, 1, 1, slab_rows, off)
     if ru is not None:
         units.append(ru)
         off += ru.slabs_per_unit
-    view = ModelArenaView(name, n_layers, tuple(units), off, slab_bytes)
+    return ModelArenaView(name, n_layers, tuple(units), off, slab_rows)
 
-    slabs = np.zeros((n_layers, view.slabs_per_layer, slab_bytes), np.uint8)
-    by_path = {p: a for p, a in layer_leaves}
+
+def pack_slabs(view: ModelArenaView, w_tree: Dict) -> np.ndarray:
+    """The packed host master ``[n_layers, slabs_per_layer, slab_rows,
+    ARENA_LANES]`` ARENA_WORD of ``w_tree`` (host numpy leaves) — the
+    HOST-resident source every (re-)upload scatters from."""
+    n_layers = view.n_layers
+    slabs = np.zeros((n_layers, view.slabs_per_layer, view.slab_rows,
+                      ARENA_LANES), ARENA_WORD)
+    by_path = dict(_leaf_paths(w_tree["layers"]))
     for u in view.units:
+        span = slabs[:, u.slab_offset:
+                     u.slab_offset + u.count * u.slabs_per_unit]
+        span = span.reshape(n_layers, u.count,
+                            u.slabs_per_unit * view.slab_rows, ARENA_LANES)
         for leaf in u.leaves:
             arr = np.ascontiguousarray(by_path[leaf.path])
-            # [L, count, unit_elems*itemsize] raw bytes of this leaf
-            raw = arr.reshape(n_layers, u.count, -1).view(np.uint8)
-            span = slabs[:, u.slab_offset:
-                         u.slab_offset + u.count * u.slabs_per_unit]
-            span = span.reshape(n_layers, u.count,
-                                u.slabs_per_unit * slab_bytes)
-            span[:, :, leaf.offset:leaf.offset + leaf.nbytes] = raw
-    return view, slabs
+            span[:, :, leaf.offset:leaf.offset + leaf.rows] = _to_words(
+                arr.reshape(n_layers, u.count, -1), leaf)
+    return slabs
 
 
 # ---------------------------------------------------------------------------
@@ -240,21 +306,26 @@ def slabs_for_config(cfg: ModelConfig, slab_bytes: int = DEFAULT_SLAB_BYTES
                      ) -> int:
     """Arena slabs a fully resident model needs, from the config alone.
 
-    Mirrors :func:`build_view_and_slabs` geometry: per layer, E expert
-    units (3 matrices each) + one rest unit (router [+ shared experts], or
-    the whole dense MLP).
+    Mirrors :func:`build_view` geometry: per layer, E expert units (3
+    matrices each) + one rest unit (router [+ shared experts], or the
+    whole dense MLP), every leaf padded to whole arena rows.
     """
     d, isz = cfg.d_model, _cfg_itemsize(cfg)
-    n_mats = 3 if cfg.mlp_kind == "swiglu" else 2
+    slab_rows = slab_bytes // _ROW_BYTES
+
+    def unit_slabs(*leaves: Tuple[int, int]) -> int:
+        rows = sum(_leaf_rows(n, size) for n, size in leaves)
+        return math.ceil(rows / slab_rows)
+
     if cfg.is_moe:
-        expert_bytes = 3 * d * cfg.d_ff * isz
-        rest_bytes = d * cfg.n_experts * 4                 # f32 router
+        per_layer = cfg.n_experts * unit_slabs(*[(d * cfg.d_ff, isz)] * 3)
+        rest = [(d * cfg.n_experts, 4)]                     # f32 router
         if cfg.n_shared_experts:
-            rest_bytes += 3 * d * cfg.n_shared_experts * cfg.d_ff * isz
-        per_layer = (cfg.n_experts * math.ceil(expert_bytes / slab_bytes)
-                     + math.ceil(rest_bytes / slab_bytes))
+            rest += [(d * cfg.n_shared_experts * cfg.d_ff, isz)] * 3
+        per_layer += unit_slabs(*rest)
     else:
-        per_layer = math.ceil(n_mats * d * cfg.d_ff * isz / slab_bytes)
+        n_mats = 3 if cfg.mlp_kind == "swiglu" else 2
+        per_layer = unit_slabs(*[(d * cfg.d_ff, isz)] * n_mats)
     return cfg.n_layers * per_layer
 
 
@@ -277,18 +348,11 @@ class Residency:
     rev: int = -1                  # bumped per activation (table cache key)
 
 
-_ARENA_SCATTER = None
-_ARENA_GATHER = None
+#: One donated-buffer scatter of packed slab rows into the arena.
+_arena_scatter = jax.jit(lambda a, i, r: a.at[i].set(r), donate_argnums=0)
 
-
-def _arena_scatter(arena, ids, rows):
-    """One donated-buffer scatter of packed slab rows into the arena."""
-    global _ARENA_SCATTER
-    if _ARENA_SCATTER is None:
-        _ARENA_SCATTER = jax.jit(
-            lambda a, i, r: a.at[i].set(r),
-            donate_argnums=donate_argnums(0))
-    return _ARENA_SCATTER(arena, ids, rows)
+#: One gather of whole slabs: builds the compacted / resized arena.
+_arena_gather = jax.jit(lambda a, i: a[i])
 
 
 class WeightArena:
@@ -296,6 +360,7 @@ class WeightArena:
 
     def __init__(self, *, slab_bytes: int = DEFAULT_SLAB_BYTES, device=None):
         self.slab_bytes = slab_bytes
+        self.slab_rows = slab_bytes // _ROW_BYTES
         self.device = device
         self.slot_budget = 0
         self.arena: Optional[jax.Array] = None
@@ -322,10 +387,9 @@ class WeightArena:
     def add_model(self, name: str, cfg: ModelConfig, w_tree: Dict) -> None:
         """Register a cold model: pack its host master slabs + build the
         static view.  No device memory is touched."""
-        view, slabs = build_view_and_slabs(name, cfg, w_tree,
-                                           slab_bytes=self.slab_bytes)
+        view = build_view(name, cfg, w_tree, slab_bytes=self.slab_bytes)
         self.views[name] = view
-        self.host_slabs[name] = slabs
+        self.host_slabs[name] = pack_slabs(view, w_tree)
 
     def finalize(self, slot_budget: Optional[int] = None, *,
                  allocate: bool = True) -> None:
@@ -340,9 +404,9 @@ class WeightArena:
         self.slot_budget = slot_budget
         self.free_list = list(range(slot_budget - 1, -1, -1))
         if allocate:
-            arena = jnp.zeros((slot_budget, self.slab_bytes), jnp.uint8)
-            self.arena = jax.device_put(arena, self.device) \
-                if self.device is not None else arena
+            self.arena = jnp.zeros(
+                (slot_budget, self.slab_rows, ARENA_LANES), ARENA_WORD,
+                device=self.device)
 
     # ------------------------------------------------------------------
     # accounting
@@ -525,8 +589,9 @@ class WeightArena:
             return {"slot_budget": old_budget, "evicted": 0, "moved": 0}
         if new_budget > old_budget:
             if self.arena is not None:
-                pad = jnp.zeros((new_budget - old_budget, self.slab_bytes),
-                                self.arena.dtype)
+                pad = jnp.zeros(
+                    (new_budget - old_budget,) + self.arena.shape[1:],
+                    self.arena.dtype, device=self.arena.sharding)
                 self.arena = jnp.concatenate([self.arena, pad], axis=0)
             self.free_list = list(range(new_budget - 1, old_budget - 1, -1)) \
                 + self.free_list
@@ -556,10 +621,7 @@ class WeightArena:
         perm = np.zeros(new_budget, np.int32)
         perm[:k] = np.asarray(old_ids, np.int32) if k else []
         if self.arena is not None:
-            global _ARENA_GATHER
-            if _ARENA_GATHER is None:
-                _ARENA_GATHER = jax.jit(lambda a, i: a[i])
-            self.arena = _ARENA_GATHER(self.arena, jnp.asarray(perm))
+            self.arena = _arena_gather(self.arena, jnp.asarray(perm))
         next_id = 0
         for name in sorted(self.residency):
             res = self.residency[name]
@@ -582,7 +644,8 @@ class WeightArena:
         res = self.residency[name]
         if self.arena is not None:
             ids = res.slots[layers].reshape(-1)
-            rows = self.host_slabs[name][layers].reshape(-1, self.slab_bytes)
+            rows = self.host_slabs[name][layers].reshape(
+                (-1,) + self.arena.shape[1:])
             self.arena = _arena_scatter(self.arena, jnp.asarray(ids),
                                         jnp.asarray(rows))
         res.uploaded[layers] = True

@@ -17,11 +17,14 @@ interpret mode against ``ref.flash_attention``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -80,7 +83,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     scale: float, block_q: int = 128, block_k: int = 128,
-                    causal: bool = True, interpret: bool = True) -> jax.Array:
+                    causal: bool = True, interpret: Optional[bool] = None) -> jax.Array:
     """q: [B,S,H,D]; k/v: [B,T,KV,D] -> [B,S,H,D] (causal, GQA)."""
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
@@ -109,5 +112,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -13,11 +13,14 @@ row masking are resolved before the DMA.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _moe_gemm_kernel(offsets_ref, x_ref, w_ref, o_ref, acc_ref, *,
@@ -49,7 +52,7 @@ def _moe_gemm_kernel(offsets_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
 def moe_gemm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
              block_n: int = 128, block_m: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: Optional[bool] = None) -> jax.Array:
     """x: [N,K] token-sorted; w: [E,K,M]; group_sizes: [E] -> [N,M]."""
     N, K = x.shape
     E, _, M = w.shape
@@ -75,5 +78,5 @@ def moe_gemm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, M), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(offsets, x, w)

@@ -3,7 +3,8 @@
 Implementation selection:
   * ``xla``     — pure-jnp reference (ref.py).  Default; used by the
                   distributed dry-run so cost_analysis sees real FLOPs.
-  * ``pallas``  — pl.pallas_call TPU kernels, run in interpret mode on CPU.
+  * ``pallas``  — pl.pallas_call TPU kernels: compiled on a TPU, run in
+                  interpret mode elsewhere (``resolve_interpret``).
 
 Select globally via :func:`set_default_impl` or per-call via ``impl=``.
 """
@@ -102,11 +103,6 @@ def paged_kv_write(pool, kv_flat, pages, slots):
             + jnp.arange(e, dtype=jnp.int32)[None, :])
     return pool.at[rows[:, None], cols].set(
         kv_flat.astype(pool.dtype), mode="drop")
-
-
-def donate_argnums(*argnums):
-    """Donation argnums, disabled on CPU where XLA cannot alias buffers."""
-    return () if jax.default_backend() == "cpu" else argnums
 
 
 # --- grouped expert GEMM ---------------------------------------------------

@@ -19,11 +19,14 @@ it per token.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +85,7 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
 def contiguous_decode_attention(q: jax.Array, cache_k: jax.Array,
                                 cache_v: jax.Array, lengths: jax.Array, *,
                                 scale: float, block_t: int = 256,
-                                interpret: bool = True) -> jax.Array:
+                                interpret: Optional[bool] = None) -> jax.Array:
     """q: [B,1,H,D]; cache: [B,T,KV,D]; lengths: [B] -> [B,1,H,D]."""
     B, _, H, D = q.shape
     T, KV = cache_k.shape[1], cache_k.shape[2]
@@ -110,7 +113,7 @@ def contiguous_decode_attention(q: jax.Array, cache_k: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lengths.astype(jnp.int32), qs, cache_k, cache_v)
 
 
@@ -169,7 +172,7 @@ def _paged_kernel(page_table_ref, lengths_ref, q_ref, pages_ref, o_ref,
 
 def paged_decode_attention(q: jax.Array, kv_pages: jax.Array,
                            page_table: jax.Array, lengths: jax.Array, *,
-                           scale: float, interpret: bool = True) -> jax.Array:
+                           scale: float, interpret: Optional[bool] = None) -> jax.Array:
     """Decode attention through the virtualizer's page table.
 
     q:          [B,1,H,D]
@@ -203,7 +206,7 @@ def paged_decode_attention(q: jax.Array, kv_pages: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qs, kv_pages)
 
@@ -269,7 +272,7 @@ def _paged_mla_kernel(page_table_ref, lengths_ref, q_ref, pages_ref, o_ref,
 def paged_mla_decode_attention(q: jax.Array, kv_pages: jax.Array,
                                page_table: jax.Array, lengths: jax.Array, *,
                                latent_dim: int, scale: float,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: Optional[bool] = None) -> jax.Array:
     """Absorbed-MLA decode attention through the virtualizer's page table.
 
     q:          [B,1,H, r+rp]  absorbed query [q_latent | q_rope]
@@ -304,6 +307,6 @@ def paged_mla_decode_attention(q: jax.Array, kv_pages: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, latent_dim), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qs, kv_pages)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, h0_ref,
                 y_ref, hout_ref, h_ref, *, chunk: int):
@@ -69,7 +71,7 @@ def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, h0_ref,
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B_: jax.Array,
              C_: jax.Array, *, chunk: int = 64,
              h0: Optional[jax.Array] = None, block_h: int = 8,
-             interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+             interpret: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:
     """Same contract as ``ref.ssd_scan``.
 
     x: [B,S,H,P]; dt: [B,S,H]; A: [H]; B_/C_: [B,S,G,N]; h0: [B,H,P,N].
@@ -111,6 +113,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B_: jax.Array,
             jax.ShapeDtypeStruct((Bb, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_h, P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, A, Bh, Ch, h0)
     return y, h_final

@@ -17,7 +17,7 @@ import math
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 # TPU v5e roofline constants (per chip)
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -29,7 +29,7 @@ HBM_BYTES = 16 * 1024 ** 3        # 16 GiB
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
@@ -37,7 +37,14 @@ def make_host_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    # the sharding strategies place arrays through NamedSharding specs and
+    # let the partitioner propagate the rest: Auto axes, not the Explicit
+    # default of newer ``jax.make_mesh``
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_chip_count(mesh: Mesh) -> int:

@@ -91,6 +91,9 @@ def main(argv: Optional[list] = None) -> None:
                          "replay with `python -m repro.launch.replay PATH`")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
+
     if args.dry_run:
         from repro.launch import dryrun
         assert args.arch, "--arch required with --dry-run"

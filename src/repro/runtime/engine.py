@@ -254,6 +254,14 @@ class ModelRunner:
 
         return writer
 
+    @staticmethod
+    def _keep_logits(reqs: List[Request], logits: jax.Array) -> None:
+        """Hand each opted-in request the [vocab] logits row its first
+        token was sampled from."""
+        for i, req in enumerate(reqs):
+            if req.keep_prompt_logits:
+                req.prompt_logits = np.asarray(logits[i])
+
     def _commit_prefill(self, req: Request, tok: int) -> int:
         slot = self.free_slot()
         assert slot is not None
@@ -267,6 +275,7 @@ class ModelRunner:
     def _commit_group(self, group: PrefillGroup, logits: jax.Array
                       ) -> List[int]:
         toks = np.asarray(sample(logits))
+        self._keep_logits(group.requests, logits)
         return [self._commit_prefill(req, int(toks[i]))
                 for i, req in enumerate(group.requests)]
 
@@ -383,6 +392,7 @@ class ModelRunner:
             logits, self.cache = self._prefill(
                 self.params, jnp.asarray(ids[None, :]), self.cache,
                 jnp.int32(slot), jnp.int32(req.prompt_tokens))
+            self._keep_logits([req], logits)
             slots.append(self._commit_prefill(
                 req, int(sample(logits)[0])))
         return slots
@@ -601,8 +611,18 @@ class CrossPoolEngine:
         devs = jax.devices()
         self.kv_device, self.w_device = devs[0], devs[-1]
 
-        params = {n: build_model(c).init(jax.random.PRNGKey(i))
-                  for i, (n, c) in enumerate(models.items())}
+        # each model's params are built, split and offloaded in turn, so
+        # one full tree is on the device at a time; fused-fallback
+        # families keep theirs for their runners
+        keys = {n: jax.random.PRNGKey(i) for i, n in enumerate(models)}
+        fallback_params: Dict[str, Dict] = {}
+
+        def init_params(name: str) -> Dict:
+            tree = build_model(models[name]).init(keys[name])
+            if not split_exec.supports_split(models[name]):
+                fallback_params[name] = tree
+            return tree
+
         # the pool dtype is the lowest common denominator of the colocated
         # models (heterogeneous models reinterpret the same untyped pages)
         pool_dtype = (jnp.float32
@@ -612,7 +632,8 @@ class CrossPoolEngine:
         # it; an all-fallback engine keeps host-side page accounting only
         any_split = any(split_exec.supports_split(c) for c in models.values())
         self.kv_pool, self.w_pool, self.pooled = build_pools(
-            models, params, kv_device=self.kv_device, w_device=self.w_device,
+            models, init_params, kv_device=self.kv_device,
+            w_device=self.w_device,
             page_budget=page_budget, page_bytes=page_bytes,
             pool_dtype=pool_dtype, allocate_device_pool=any_split,
             slot_budget=slot_budget, slab_bytes=slab_bytes,
@@ -742,13 +763,13 @@ class CrossPoolEngine:
                 else self.host_steps.get(n))
             for n in models if self.pooled[n].stage_fns is not None
         }
-        # paged models hold NO full param tree: the init-time tree is split
-        # into pooled kv_params + the arena's packed host masters, and the
-        # full copy is dropped here (fallback families keep theirs)
+        # paged models hold NO full param tree: each init-time tree was
+        # split into pooled kv_params + the arena's packed host masters,
+        # and its full copy dropped (fallback families keep theirs)
         self.runners = {
             n: ModelRunner(
                 n, c,
-                None if n in prefill_steps else params[n], self.virt,
+                fallback_params.get(n), self.virt,
                 max_batch=max_batch, max_ctx=max_ctx,
                 mode=self.mode, pooled=self.pooled[n],
                 prefill_step=prefill_steps.get(n))

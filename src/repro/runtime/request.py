@@ -28,11 +28,15 @@ class Request:
     # index this prompt's KV.  Only requests with real ``prompt_ids`` ever
     # participate — synthetic prompts are silently cache-cold.
     cache: bool = True
+    # keep the logits row the first token was sampled from (filled in as
+    # ``prompt_logits`` at prefill) — for checks against a reference model
+    keep_prompt_logits: bool = False
     phase: Phase = Phase.QUEUED
     # --- progress -------------------------------------------------------
     generated: int = 0
     output_ids: List[int] = field(default_factory=list)
     eos_seen: bool = False
+    prompt_logits: Optional[object] = None   # np [vocab] when kept
     # --- latency bookkeeping ---------------------------------------------
     admit_time: float = 0.0
     first_token_time: float = 0.0

@@ -221,12 +221,10 @@ backend_config={"known_trip_count":{"n":"2"}}
 
 
 def test_cpa02_dropped_donation_on_synthetic_hlo():
-    found = ja.audit_hlo(SYNTH_NO_ALIAS, pool_param=0, n_layers=2, k=1,
-                         expect_donation=True)
+    found = ja.audit_hlo(SYNTH_NO_ALIAS, pool_param=0, n_layers=2, k=1)
     assert "CPA02" in [f.check for f in found]
-    # never requested -> never "dropped"
-    found = ja.audit_hlo(SYNTH_NO_ALIAS, pool_param=0, n_layers=2, k=1,
-                         expect_donation=False)
+    # the aliased pool parameter is not a finding
+    found = ja.audit_hlo(SYNTH_ALIASED, pool_param=4, n_layers=2, k=4)
     assert "CPA02" not in [f.check for f in found]
 
 
@@ -242,9 +240,8 @@ def test_cpa04_dispatch_structure():
     found = ja.audit_hlo(SYNTH_ALIASED, pool_param=4, n_layers=2, k=8)
     assert [f.check for f in found] == ["CPA04"]
     # a module with no while at all fails the K=1 layer-scan claim too
-    found = ja.audit_hlo(SYNTH_NO_ALIAS, pool_param=0, n_layers=2, k=1,
-                         expect_donation=False)
-    assert [f.check for f in found] == ["CPA04"]
+    found = ja.audit_hlo(SYNTH_NO_ALIAS, pool_param=0, n_layers=2, k=1)
+    assert [f.check for f in found] == ["CPA02", "CPA04"]
 
 
 def test_alias_parser_roundtrips_real_donated_program():

@@ -6,6 +6,7 @@ totals using known_trip_count.
 """
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.launch import hlo_analysis as ha
 
@@ -62,7 +63,7 @@ def test_known_trip_count_overrides_depth_guess():
 
 def test_collective_counting_with_loops():
     """psum inside a scan counts once per trip."""
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = jax.make_mesh((1,), ("x",), axis_types=(AxisType.Auto,))
 
     def f(v):
         def body(c, _):

@@ -1,6 +1,7 @@
 """Kernel allclose sweeps: every Pallas kernel vs. its pure-jnp oracle.
 
-Kernels run in interpret mode (CPU executes the kernel body), oracles are
+Kernels run in interpret mode off the TPU (CPU executes the kernel body;
+``repro.kernels.resolve_interpret`` decides it from the backend), oracles are
 ``repro.kernels.ref``.  Sweeps cover shapes (aligned + ragged), dtypes, and
 GQA group structure; hypothesis drives property tests on the invariants.
 """
@@ -10,7 +11,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ref
+from repro.kernels import ref, resolve_interpret
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gemm import moe_gemm
 from repro.kernels.paged_attention import (contiguous_decode_attention,
@@ -267,3 +268,13 @@ def test_ssd_scan_with_initial_state():
                                np.asarray(y_full), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_interpret_mode_follows_the_backend():
+    """Off the TPU a kernel interprets unless told not to (a compile for a
+    described TPU passes ``interpret=False``); the choice is the backend's,
+    made in one place."""
+    assert jax.default_backend() != "tpu"
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True

@@ -94,8 +94,16 @@ def test_streaming_prefill_matches_full_tree_bit_exact(name, dtype):
     sp = StreamingPrefill(pooled[name])
     got, virt.pool = sp(tokens, seq, virt.pool, _writer(virt, name, 1, seq))
 
-    assert np.array_equal(np.asarray(want), np.asarray(got)), \
-        f"{name}/{dtype}: streaming arena prefill logits != full-tree"
+    # bf16 streams are bit-exact.  In float32 the fused full-tree program
+    # and the per-layer stage programs are two different XLA programs,
+    # which may order a reduction differently: XLA promises no bit
+    # equality between them, and they differ by ~2e-7 relative (a couple
+    # of f32 ulps).  A wrong slab or page moves logits by O(1).
+    tol = (dict(rtol=1e-5, atol=1e-5) if dtype == "float32"
+           else dict(rtol=0, atol=0))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), **tol,
+        err_msg=f"{name}/{dtype}: streaming arena prefill logits != full-tree")
     # streaming activation: started cold, ended fully uploaded, one layer
     # upload per layer
     assert arena.residency[name].uploaded.all()
@@ -105,8 +113,11 @@ def test_streaming_prefill_matches_full_tree_bit_exact(name, dtype):
     r0, r1 = virt.requests[0], virt.requests[1]
     for t0, t1 in zip(r0.tables, r1.tables):
         for p0, p1 in zip(t0, t1):
-            assert np.array_equal(pool_np[p0], pool_np[p1]), \
-                f"{name}/{dtype}: prompt KV bytes differ in the pool"
+            # the same two-program f32 reassociation reaches the KV of
+            # every layer after the first
+            np.testing.assert_allclose(
+                pool_np[p1], pool_np[p0], **tol,
+                err_msg=f"{name}/{dtype}: prompt KV differs in the pool")
 
 
 def test_scheduler_prefill_interleaves_and_matches_sequential():
@@ -183,14 +194,14 @@ def test_eviction_between_prefill_and_first_decode():
     # pinned: the prefill-to-first-decode window is eviction-proof
     with pytest.raises(ValueError):
         arena.evict(name)
-    logits1, _ = step(next_tok, virt.pool, tables, lengths)
+    logits1, virt.pool = step(next_tok, virt.pool, tables, lengths)
 
     # now simulate the mid-stream eviction: pins dropped (request aborted
     # elsewhere / accounting bug being defended against), model evicted
     arena.unpin(name)
     arena.evict(name)
     assert not arena.is_resident(name)
-    logits2, _ = step(next_tok, virt.pool, tables, lengths)
+    logits2, virt.pool = step(next_tok, virt.pool, tables, lengths)
     assert arena.is_resident(name) and arena.residency[name].uploaded.all()
     assert np.array_equal(np.asarray(logits1), np.asarray(logits2)), \
         "re-activation after mid-stream eviction changed decode logits"

@@ -350,6 +350,34 @@ def test_real_prompt_ids_round_trip_and_length_contract():
         engine.step()
 
 
+def test_kept_prompt_logits_match_unpooled_forward():
+    """A request that asks for its first-token logits gets the row its
+    first token was sampled from, and in float32 that row is the model's
+    own unpooled forward (same ``PRNGKey(i)`` weights) — pooled pages and
+    arena slabs add no error.  Prompts are a whole prefill bucket, so the
+    MoE routes with the same expert capacity in both."""
+    names = (MOE, MLA)
+    engine = _engine(names=names)
+    rng = np.random.default_rng(5)
+    reqs = [Request(i, name, 16, 2, 0.0, keep_prompt_logits=True,
+                    prompt_ids=rng.integers(0, _models()[name].vocab_size,
+                                            16).astype(np.int32))
+            for i, name in enumerate(names)]
+    reqs.append(Request(2, MOE, 16, 2, 0.0, prompt_ids=reqs[0].prompt_ids))
+    for r in reqs:
+        engine.submit(r)
+    engine.drain()
+    assert reqs[2].prompt_logits is None
+    for i, (name, cfg) in enumerate(_models(names).items()):
+        model = build_model(cfg)
+        want, _ = model.forward(model.init(jax.random.PRNGKey(i)),
+                                jnp.asarray(reqs[i].prompt_ids)[None])
+        got = reqs[i].prompt_logits
+        assert int(np.argmax(got)) == reqs[i].output_ids[0]
+        np.testing.assert_allclose(got, np.asarray(want[0, -1]),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_reset_stats_opens_window_and_prunes_terminal_handles():
     """reset_stats() starts a fresh latency window and prunes terminal
     handles (the memory bound for long-lived sessions)."""

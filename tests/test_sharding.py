@@ -16,6 +16,9 @@ if "XLA_FLAGS" not in os.environ and __name__ != "__main__":
 
     def _run_self():
         env = dict(os.environ)
+        # forced host devices: the child runs on the CPU, never on a chip
+        # its parent may hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
         r = subprocess.run([sys.executable, "-m", "pytest", _HERE, "-q",
@@ -28,15 +31,13 @@ if "XLA_FLAGS" not in os.environ and __name__ != "__main__":
                 f"{r.stderr[-2000:]}")
 
     def test_sharding_suite_subprocess():
-        # the known-broken seq-sharded tests are xfail-annotated INSIDE the
-        # subprocess module (see _axis_size_xfail below), so a non-zero
-        # exit here is a NEW sharding regression, not the seed failure
         _run_self()
 
 else:
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.sharding import AxisType
     from repro.configs import SHAPES_BY_NAME, get_smoke_config
     from repro.kernels import ref
     from repro.models import build_model
@@ -44,8 +45,9 @@ else:
                                               make_seq_mla_decode_attn)
     from repro.sharding.strategies import make_strategy
 
-    def _mesh():
-        return jax.make_mesh((2, 4), ("data", "model"))
+    def _mesh(shape=(2, 4)):
+        return jax.make_mesh(shape, ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
     def test_device_count():
         assert len(jax.devices()) == 8
@@ -104,18 +106,6 @@ else:
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 
-    # Upstream XLA bug on this build (jax 0.4.37, CPU SPMD partitioner):
-    # a lax.scan whose body consumes stacked layer weights [L, ...] with a
-    # sharded non-scan dim miscompiles (wrong numerics, preceded by
-    # "Involuntary full rematerialization" partitioner errors).  Minimal
-    # repro: scan(lambda c, W: (c @ W @ ones, None), x, Ws) with Ws
-    # sharded P(None, "model", None) over an 8-way host mesh -> max err
-    # O(1).  Only the monolithic strategy's TP-within-replica specs hit
-    # the bad pattern at smoke scale (crosspool's pool-wide specs degrade
-    # to replicated on non-divisible smoke dims); drop on a jax upgrade.
-    _SPMD_SCAN_BUG = ("upstream XLA CPU SPMD miscompile: scan over "
-                      "stacked sharded layer weights (jax 0.4.37)")
-
     @pytest.mark.parametrize("strategy", ["monolithic", "crosspool"])
     @pytest.mark.parametrize("arch", [
         "qwen3-moe-235b-a22b",
@@ -151,13 +141,8 @@ else:
             c_sh = jax.device_put(cache, strat.cache_shardings(cache))
             got, new_cache = jax.jit(step)(p_sh, next_tok, c_sh,
                                            jnp.int32(seq))
-        try:
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       rtol=2e-4, atol=2e-4)
-        except AssertionError:
-            if strategy == "monolithic" and arch != "zamba2-1.2b":
-                pytest.xfail(_SPMD_SCAN_BUG)
-            raise
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
 
     def test_elastic_reshard_across_meshes():
         """Checkpoint written under a (2,4) mesh restores onto a (4,2)
@@ -172,8 +157,8 @@ else:
         cfg = _gsc("qwen3-14b").replace(dtype="float32")
         model = _bm(cfg)
         params = model.init(jax.random.PRNGKey(7))
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
-        mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = _mesh((2, 4))
+        mesh_b = _mesh((4, 2))
         strat_a = _ms("train", mesh_a, cfg, _SBN["train_4k"])
         strat_b = _ms("train", mesh_b, cfg, _SBN["train_4k"])
         p_a = jax.device_put(params, strat_a.params_shardings(params))

@@ -177,7 +177,8 @@ def test_evict_reactivate_reproduces_identical_logits():
     lengths = jnp.full((B,), seq, jnp.int32)
     next_tok = jnp.zeros((B,), jnp.int32)
 
-    logits1, _ = step(next_tok, pool0, tables, lengths)
+    # the step donates its pool: thread the returned buffer through
+    logits1, pool1 = step(next_tok, pool0, tables, lengths)
     rev1 = arena.residency[name].rev
     arena.evict(name)                    # both models idle -> evictable
     arena.evict(MLA)
@@ -186,7 +187,7 @@ def test_evict_reactivate_reproduces_identical_logits():
     arena.activate(MLA)                  # reshuffle the free list
     arena.activate(name)                 # re-upload from host masters
     assert arena.residency[name].rev != rev1
-    logits2, _ = step(next_tok, pool0, tables, lengths)
+    logits2, _ = step(next_tok, pool1, tables, lengths)
     assert np.array_equal(np.asarray(logits1), np.asarray(logits2))
 
 
