@@ -136,7 +136,8 @@ def _jitted_names(tree: ast.AST) -> Set[str]:
         if isinstance(node, ast.Call):
             callee = _dotted(node.func)
             first = node.args[0] if node.args else None
-            if callee in ("jax.jit", "jit") and isinstance(first, ast.Name):
+            if callee in ("jax.jit", "jit", "jit_program") \
+                    and isinstance(first, ast.Name):
                 names.add(first.id)
             if callee == "partial" and first is not None \
                     and _dotted(first) in ("jax.jit", "jit"):
@@ -146,8 +147,16 @@ def _jitted_names(tree: ast.AST) -> Set[str]:
             if callee.endswith("lax.scan") and isinstance(first, ast.Name):
                 names.add(first.id)
             if callee == "StageFns":
-                names.update(a.id for a in node.args
-                             if isinstance(a, ast.Name))
+                # a stage may be wrapped in its device scope:
+                # ``scoped("attn", attn_stage)``
+                for a in node.args:
+                    if isinstance(a, ast.IfExp):
+                        a = a.orelse
+                    if isinstance(a, ast.Call) \
+                            and _dotted(a.func) == "scoped" and a.args:
+                        a = a.args[-1]
+                    if isinstance(a, ast.Name):
+                        names.add(a.id)
         elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             for dec in node.decorator_list:
                 d = dec.func if isinstance(dec, ast.Call) else dec

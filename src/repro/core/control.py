@@ -35,9 +35,18 @@ upload instead of waiting for it (DESIGN.md §6).
 Families that bypass split execution (SSM/hybrid/enc-dec/SWA) decode
 through the fused dense-cache ``model.decode_step`` program compiled by
 ``runtime.engine.ModelRunner`` — there is no separate step class for them.
+
+Every program compiles under a stable name of its own
+(:func:`program_name`: ``decode_<model>`` for a fused step,
+``decode_<stage>_<model>`` / ``prefill_<stage>_<model>`` for the stage
+programs), and inside it the stage functions open their device scopes
+(``split_exec.SCOPES``), so a profiler trace names the model, the stage
+and the kernel of every device op.
 """
 from __future__ import annotations
 
+import functools
+import re
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -45,6 +54,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.pools import PooledModel, transfer
+
+
+def program_name(kind: str, model: str) -> str:
+    """``<kind>_<model>`` with the model name's punctuation as ``_``: the
+    program is ``jit_<name>`` in the HLO and in the profiler's events."""
+    return f"{kind}_{re.sub(r'[^0-9A-Za-z_]', '_', model)}"
+
+
+def jit_program(fn: Callable, kind: str, model: str, **jit_kw):
+    """``jax.jit(fn, **jit_kw)`` compiled under :func:`program_name`."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = program_name(kind, model)
+    return jax.jit(program, **jit_kw)
 
 
 class HostDrivenStep:
@@ -55,18 +80,23 @@ class HostDrivenStep:
         self.kv_device = kv_device
         self.w_device = w_device
         fns = pooled.stage_fns
+        m = pooled.cfg.name
         # execution placement follows the committed pool buffers: attention
         # stages run where kv_params live, FFN stages where the arena lives.
-        self._embed = jax.jit(fns.embed)
-        self._attn = jax.jit(fns.attn_stage, donate_argnums=2)
-        self._ffn = jax.jit(fns.ffn_stage)
-        self._combine = jax.jit(fns.combine)
-        self._logits = jax.jit(fns.logits)
+        self._embed = jit_program(fns.embed, "decode_embed", m)
+        self._attn = jit_program(fns.attn_stage, "decode_attn", m,
+                                 donate_argnums=2)
+        self._ffn = jit_program(fns.ffn_stage, "decode_ffn", m)
+        self._combine = jit_program(fns.combine, "decode_combine", m)
+        self._logits = jit_program(fns.logits, "decode_logits", m)
         # prompt-phase stage programs (the pipeline scheduler interleaves
         # prefill batches through the same per-layer machinery)
-        self._pembed = jax.jit(fns.prefill_embed)
-        self._pattn = jax.jit(fns.prefill_attn)
-        self._plogits = jax.jit(fns.prefill_logits)
+        self._pembed = jit_program(fns.prefill_embed, "prefill_embed", m)
+        self._pattn = jit_program(fns.prefill_attn, "prefill_attn", m)
+        self._pffn = jit_program(fns.prefill_ffn, "prefill_ffn", m)
+        self._pcombine = jit_program(fns.prefill_combine, "prefill_combine",
+                                     m)
+        self._plogits = jit_program(fns.prefill_logits, "prefill_logits", m)
 
     def __call__(self, tokens, pool, page_tables, lengths
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -153,28 +183,35 @@ class StreamingPrefill:
             # model, whether a prompt runs here or via the scheduler)
             self._embed = share._pembed
             self._attn = share._pattn
-            self._ffn = share._ffn
-            self._combine = share._combine
+            self._ffn = share._pffn
+            self._combine = share._pcombine
             self._logits = share._plogits
         else:
             fns = pooled.stage_fns
-            self._embed = jax.jit(fns.prefill_embed)
-            self._attn = jax.jit(fns.prefill_attn)
-            self._ffn = jax.jit(fns.ffn_stage)
-            self._combine = jax.jit(fns.combine)
-            self._logits = jax.jit(fns.prefill_logits)
+            m = pooled.cfg.name
+            self._embed = jit_program(fns.prefill_embed, "prefill_embed", m)
+            self._attn = jit_program(fns.prefill_attn, "prefill_attn", m)
+            self._ffn = jit_program(fns.prefill_ffn, "prefill_ffn", m)
+            self._combine = jit_program(fns.prefill_combine,
+                                        "prefill_combine", m)
+            self._logits = jit_program(fns.prefill_logits, "prefill_logits",
+                                       m)
         # prefix-cache programs (DESIGN.md §11) are StreamingPrefill-local
         # in BOTH lowering modes: suffix shapes depend on the fork point,
         # never on the decode lowering, so there is nothing to share
         fns = pooled.stage_fns
+        m = pooled.cfg.name
         self._suffix_attn = None
         self._suffix_ffn = None
         self._route = None
         if getattr(fns, "suffix_attn", None) is not None:
-            self._suffix_attn = jax.jit(fns.suffix_attn, static_argnums=(5,))
-            self._suffix_ffn = jax.jit(fns.suffix_ffn, static_argnums=(5,))
+            self._suffix_attn = jit_program(
+                fns.suffix_attn, "prefill_suffix_attn", m,
+                static_argnums=(5,))
+            self._suffix_ffn = jit_program(
+                fns.suffix_ffn, "prefill_suffix_ffn", m, static_argnums=(5,))
         if getattr(fns, "prefill_route", None) is not None:
-            self._route = jax.jit(fns.prefill_route)
+            self._route = jit_program(fns.prefill_route, "prefill_route", m)
         #: per-layer routing of the last captured prompt pass:
         #: np.ndarray [S, L, k] (None when not captured / dense)
         self.captured_routes = None
@@ -325,13 +362,17 @@ class PagedFusedStep:
                 x = fns.combine(x, ffn_out)
                 return (x, pool), None
 
-            (x, pool), _ = jax.lax.scan(
-                body, (x, pool), jnp.arange(fns.n_layers))
+            with jax.named_scope("loop"):
+                (x, pool), _ = jax.lax.scan(
+                    body, (x, pool), jnp.arange(fns.n_layers))
             logits = fns.logits(p_kv, x)
-            out = postprocess(logits) if postprocess is not None else logits
+            with jax.named_scope("head"):
+                out = (postprocess(logits) if postprocess is not None
+                       else logits)
             return out, pool
 
-        self._step = jax.jit(step, donate_argnums=4)
+        self._step = jit_program(step, "decode", pooled.cfg.name,
+                                 donate_argnums=4)
 
     def __call__(self, tokens, pool, page_tables, lengths
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -402,25 +443,33 @@ class MultiStepFusedStep:
                     x = fns.combine(x, ffn_out)
                     return (x, pool), None
 
-                (x, pool), _ = jax.lax.scan(
-                    body, (x, pool), jnp.arange(fns.n_layers))
+                with jax.named_scope("loop"):
+                    (x, pool), _ = jax.lax.scan(
+                        body, (x, pool), jnp.arange(fns.n_layers))
                 logits = fns.logits(p_kv, x)
-                sampled = sample_on_device(
-                    logits, key, t, temperature=temperature, top_k=top_k)
-                out_tok = jnp.where(done, jnp.int32(-1), sampled)
-                next_tok = jnp.where(done, toks, sampled)
-                new_len = jnp.where(done, lens, lens + 1)
-                hit_eos = (~done) & (eos_ids >= 0) & (sampled == eos_ids)
-                new_done = done | hit_eos | (steps_left <= t + 1)
+                with jax.named_scope("head"):
+                    sampled = sample_on_device(
+                        logits, key, t, temperature=temperature,
+                        top_k=top_k)
+                    out_tok = jnp.where(done, jnp.int32(-1), sampled)
+                    next_tok = jnp.where(done, toks, sampled)
+                    new_len = jnp.where(done, lens, lens + 1)
+                    hit_eos = ((~done) & (eos_ids >= 0)
+                               & (sampled == eos_ids))
+                    new_done = done | hit_eos | (steps_left <= t + 1)
                 return (next_tok, pool, new_len, new_done), out_tok
 
-            done0 = steps_left <= 0
-            (_, pool, _, _), out = jax.lax.scan(
-                inner, (tokens, pool, lengths, done0),
-                jnp.arange(n_steps))
+            # the two scans' own control (counters, the layer index, the
+            # rows' done mask) is the ``loop`` scope
+            with jax.named_scope("loop"):
+                done0 = steps_left <= 0
+                (_, pool, _, _), out = jax.lax.scan(
+                    inner, (tokens, pool, lengths, done0),
+                    jnp.arange(n_steps))
             return out, pool
 
-        self._step = jax.jit(step, donate_argnums=4)
+        self._step = jit_program(step, "decode", pooled.cfg.name,
+                                 donate_argnums=4)
 
     def __call__(self, tokens, pool, page_tables, lengths, steps_left,
                  eos_ids=None, key=None) -> Tuple[jax.Array, jax.Array]:

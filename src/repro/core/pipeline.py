@@ -137,13 +137,15 @@ class LayerPipelineScheduler:
             b.phase = "ffn"
         elif b.phase == "ffn":
             arena.prefetch_layer(b.model, b.layer)   # no-op once prefetched
-            out = step._ffn(arena.arena, arena.slot_table(b.model),
-                            b.ffn_in, b.layer)
+            ffn = step._pffn if b.prefill else step._ffn
+            out = ffn(arena.arena, arena.slot_table(b.model), b.ffn_in,
+                      b.layer)
             b.ffn_out = transfer(out, self.kv_device)        # F-to-A
             self.stage_log.append((b.batch_id, b.model, "ffn", b.layer))
             b.phase = "combine"
         elif b.phase == "combine":
-            b.x = step._combine(b.x, b.ffn_out)
+            b.x = (step._pcombine if b.prefill else step._combine)(
+                b.x, b.ffn_out)
             b.layer += 1
             if b.layer >= fns.n_layers:
                 b.logits = (step._plogits(p_kv, b.x,

@@ -25,6 +25,7 @@ from typing import Callable, Dict, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import split_exec
@@ -122,8 +123,13 @@ class KVCachePool:
 
 
 def transfer(x: jax.Array, device) -> jax.Array:
-    """The pool boundary: explicit async hidden-state transfer."""
-    return jax.device_put(x, device)
+    """The pool boundary: explicit async hidden-state transfer, issued
+    inside a ``cp.transfer`` profiler span while a JAX profiler session
+    records (the host-driven pipeline's exposed transfer time)."""
+    if not TraceAnnotation.is_enabled():
+        return jax.device_put(x, device)
+    with TraceAnnotation("cp.transfer", bytes=x.nbytes):
+        return jax.device_put(x, device)
 
 
 def build_pools(models: Dict[str, ModelConfig],

@@ -27,6 +27,7 @@ paper's serving targets.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -75,6 +76,31 @@ class StageFns(NamedTuple):
     # (arena, slot_table, ffn_input, layer) -> experts [B,S,k]
     #  (MoE only; recomputes the router's top-k choice so the prompt's
     #   routing can be captured without touching the FFN program)
+    prefill_ffn: Optional[Callable] = None      # ffn_stage's prompt twin
+    prefill_combine: Optional[Callable] = None  # combine's prompt twin
+
+
+#: The device scopes (``jax.named_scope``) the stage functions open, so a
+#: profiler trace charges each device op to the stage that issued it.
+#: Decode: ``embed``, ``attn`` (children ``kv_write``, ``kv_view``,
+#: ``attention``), ``ffn`` (children ``unpack``, ``router``, ``experts``,
+#: ``mlp``) and ``head``; ``loop`` holds the fused step's loop control
+#: (``core/control.py``).  Prompt path: ``embed``, ``prefill_attn``,
+#: ``prefill_ffn`` (same children as ``ffn``), ``kv_prompt_write``
+#: (``core/virtualizer.py``) and ``head``.
+SCOPES = ("embed", "attn", "kv_write", "kv_view", "attention", "ffn",
+          "unpack", "router", "experts", "mlp", "head", "loop",
+          "prefill_attn", "prefill_ffn", "kv_prompt_write")
+
+
+def scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` traced under the device scope ``name`` (HLO metadata only:
+    the ops and their fusion are the same)."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    return run
 
 
 def _layer_params(params: Dict, layer) -> Dict:
@@ -153,7 +179,8 @@ def make_stage_fns(cfg: ModelConfig, view: ModelView,
             else:
                 out, _ = moe_mod.apply_moe(p_l["moe"], ffn_in, cfg)
         else:
-            out = layers.apply_mlp(p_l["mlp"], ffn_in, cfg.mlp_kind)
+            with jax.named_scope("mlp"):
+                out = layers.apply_mlp(p_l["mlp"], ffn_in, cfg.mlp_kind)
         return out
 
     def combine(x, ffn_out):
@@ -241,7 +268,8 @@ def make_stage_fns(cfg: ModelConfig, view: ModelView,
                                        capacity=capacity,
                                        slot_offset=offset)
         else:
-            out = layers.apply_mlp(p_l["mlp"], ffn_in, cfg.mlp_kind)
+            with jax.named_scope("mlp"):
+                out = layers.apply_mlp(p_l["mlp"], ffn_in, cfg.mlp_kind)
         return out
 
     if cfg.is_moe:
@@ -256,9 +284,17 @@ def make_stage_fns(cfg: ModelConfig, view: ModelView,
     else:
         prefill_route = None
 
-    return StageFns(embed, attn_stage, ffn_stage, combine, logits,
-                    prefill_embed, prefill_attn, prefill_logits,
-                    cfg.n_layers, suffix_attn, suffix_ffn, prefill_route)
+    return StageFns(
+        scoped("embed", embed), scoped("attn", attn_stage),
+        scoped("ffn", ffn_stage), scoped("ffn", combine),
+        scoped("head", logits), scoped("embed", prefill_embed),
+        scoped("prefill_attn", prefill_attn),
+        scoped("head", prefill_logits), cfg.n_layers,
+        scoped("prefill_attn", suffix_attn),
+        scoped("prefill_ffn", suffix_ffn),
+        None if prefill_route is None
+        else scoped("prefill_ffn", prefill_route),
+        scoped("prefill_ffn", ffn_stage), scoped("prefill_ffn", combine))
 
 
 def split_params(params: Dict, cfg: ModelConfig) -> Tuple[Dict, Dict]:

@@ -164,11 +164,12 @@ class RequestPages:
 
 
 def _pack_write(pool, a, b, pages, slots, n_tokens, batch_index, mla):
-    a = a[batch_index, :n_tokens]
-    b = b[batch_index, :n_tokens]
-    kv = (jnp.concatenate([a, b], axis=-1) if mla
-          else jnp.stack([a, b], axis=1))
-    return paged_kv_write(pool, kv.reshape(n_tokens, -1), pages, slots)
+    with jax.named_scope("kv_prompt_write"):
+        a = a[batch_index, :n_tokens]
+        b = b[batch_index, :n_tokens]
+        kv = (jnp.concatenate([a, b], axis=-1) if mla
+              else jnp.stack([a, b], axis=1))
+        return paged_kv_write(pool, kv.reshape(n_tokens, -1), pages, slots)
 
 
 #: One donated-buffer scatter of ``n`` token rows into the flat pool.

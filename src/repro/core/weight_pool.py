@@ -219,6 +219,10 @@ class ModelArenaView:
         packed host words.  Gathering per leaf, not per layer, lets each
         leaf's rows feed its consumer without a second layer-sized copy.
         """
+        with jax.named_scope("unpack"):
+            return self._unpack(arena, row)
+
+    def _unpack(self, arena: jax.Array, row: jax.Array) -> Dict:
         out: Dict = {}
         for u in self.units:
             unit_base = (u.slab_offset

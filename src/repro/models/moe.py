@@ -115,45 +115,50 @@ def apply_moe(p: Dict, x: jax.Array, cfg: ModelConfig, *,
     E, k = cfg.n_experts, cfg.experts_per_token
     C = capacity or expert_capacity(N, cfg)
 
-    gates, experts, probs = route(p, xf, cfg)                # [N,k]x2, [N,E]
-    slot, keep = dispatch_indices(experts, E, C, offset=slot_offset)
+    with jax.named_scope("router"):
+        gates, experts, probs = route(p, xf, cfg)            # [N,k]x2, [N,E]
+        slot, keep = dispatch_indices(experts, E, C, offset=slot_offset)
 
-    # ---- dispatch: scatter tokens into [E, C, D] ---------------------------
-    flat_expert = experts.reshape(-1)                        # [N*k]
-    flat_slot = slot.reshape(-1)
-    flat_keep = keep.reshape(-1)
-    flat_dst = jnp.where(flat_keep, flat_expert * C + flat_slot, E * C)
-    token_ids = jnp.repeat(jnp.arange(N), k)                 # [N*k]
-    x_src = xf[token_ids]                                    # [N*k, D]
-    buf = jnp.zeros((E * C + 1, d), xf.dtype)
-    buf = buf.at[flat_dst].set(x_src)                        # drop row E*C
-    expert_in = buf[: E * C].reshape(E, C, d)
-    expert_in = hooks.moe_inputs(expert_in)
+    with jax.named_scope("experts"):
+        # ---- dispatch: scatter tokens into [E, C, D] -----------------------
+        flat_expert = experts.reshape(-1)                    # [N*k]
+        flat_slot = slot.reshape(-1)
+        flat_keep = keep.reshape(-1)
+        flat_dst = jnp.where(flat_keep, flat_expert * C + flat_slot, E * C)
+        token_ids = jnp.repeat(jnp.arange(N), k)             # [N*k]
+        x_src = xf[token_ids]                                # [N*k, D]
+        buf = jnp.zeros((E * C + 1, d), xf.dtype)
+        buf = buf.at[flat_dst].set(x_src)                    # drop row E*C
+        expert_in = buf[: E * C].reshape(E, C, d)
+        expert_in = hooks.moe_inputs(expert_in)
 
-    # ---- expert computation (stacked SwiGLU over the E axis) ---------------
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, p["wg"])) \
-        * jnp.einsum("ecd,edf->ecf", expert_in, p["wu"])
-    h = hooks.moe_hidden(h)
-    expert_out = jnp.einsum("ecf,efd->ecd", h, p["wd"])      # [E,C,D]
-    expert_out = hooks.moe_inputs(expert_out)
+        # ---- expert computation (stacked SwiGLU over the E axis) -----------
+        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, p["wg"])) \
+            * jnp.einsum("ecd,edf->ecf", expert_in, p["wu"])
+        h = hooks.moe_hidden(h)
+        expert_out = jnp.einsum("ecf,efd->ecd", h, p["wd"])  # [E,C,D]
+        expert_out = hooks.moe_inputs(expert_out)
 
-    # ---- combine: gather back and weight by gates --------------------------
-    flat_out = expert_out.reshape(E * C, d)
-    safe_dst = jnp.minimum(flat_dst, E * C - 1)
-    y_pairs = flat_out[safe_dst] * (gates.reshape(-1) * flat_keep)[:, None]
-    y = jax.ops.segment_sum(y_pairs.astype(jnp.float32), token_ids,
-                            num_segments=N).astype(x.dtype)
+        # ---- combine: gather back and weight by gates ----------------------
+        flat_out = expert_out.reshape(E * C, d)
+        safe_dst = jnp.minimum(flat_dst, E * C - 1)
+        y_pairs = flat_out[safe_dst] * (gates.reshape(-1)
+                                        * flat_keep)[:, None]
+        y = jax.ops.segment_sum(y_pairs.astype(jnp.float32), token_ids,
+                                num_segments=N).astype(x.dtype)
 
     # ---- shared experts (always-on residual experts; DeepSeek-style) -------
     if cfg.n_shared_experts:
-        y = y + layers.apply_mlp(p["shared"], xf, "swiglu",
-                                 hook=hooks.ffn_hidden)
+        with jax.named_scope("mlp"):
+            y = y + layers.apply_mlp(p["shared"], xf, "swiglu",
+                                     hook=hooks.ffn_hidden)
 
     # ---- load-balance aux loss (Switch): E * sum_e f_e * P_e ---------------
-    pair_onehot = jax.nn.one_hot(experts, E, dtype=jnp.float32)  # [N,k,E]
-    frac_tokens = jnp.mean(jnp.sum(pair_onehot, axis=1), axis=0)  # [E]
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs) / k
+    with jax.named_scope("router"):
+        pair_onehot = jax.nn.one_hot(experts, E, dtype=jnp.float32)  # [N,k,E]
+        frac_tokens = jnp.mean(jnp.sum(pair_onehot, axis=1), axis=0)  # [E]
+        frac_probs = jnp.mean(probs, axis=0)
+        aux = E * jnp.sum(frac_tokens * frac_probs) / k
 
     return y.reshape(orig_shape), aux
 

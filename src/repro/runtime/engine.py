@@ -75,7 +75,7 @@ from repro.configs.base import (DEFAULT_DECODE_STEPS_PER_DISPATCH,
 from repro.core.admission import (AdmissionController, AdmissionStats,
                                   PendingRequest)
 from repro.core.control import (HostDrivenStep, MultiStepFusedStep,
-                                StreamingPrefill)
+                                StreamingPrefill, jit_program)
 from repro.analysis.sanitizer import PoolSanitizer, PoolSanitizerError
 from repro.core.elastic import ElasticRebalancer
 from repro.core.errors import PoolAccountingError
@@ -91,7 +91,8 @@ from repro.models import build_model
 from repro.models.moe import expert_capacity
 from repro.runtime.flightrec import (FlightRecorder, ReplayDivergence,
                                      engine_header, pool_snapshot)
-from repro.runtime.observe import EngineObserver, MetricsRegistry, SLOMonitor
+from repro.runtime.observe import (NO_SPAN, EngineObserver, MetricsRegistry,
+                                   SLOMonitor)
 from repro.runtime.request import Phase, Request
 from repro.runtime.sampler import sample
 from repro.runtime.session import (HandleState, PrefillBatcher, PrefillGroup,
@@ -212,13 +213,14 @@ class ModelRunner:
                     cache, one)
                 return logits, cache
 
-            self._prefill = jax.jit(_prefill_dense)
+            self._prefill = jit_program(_prefill_dense, "prefill", name)
 
             def _decode(params, tokens, cache, lengths):
                 logits, cache = mdl.decode_step(params, tokens, cache, lengths)
                 return sample(logits), cache
 
-            self._decode = jax.jit(_decode, donate_argnums=(2,))
+            self._decode = jit_program(_decode, "decode", name,
+                                       donate_argnums=(2,))
 
     # ------------------------------------------------------------------
     def free_slot(self) -> Optional[int]:
@@ -1548,14 +1550,16 @@ class CrossPoolEngine:
             if len(first) >= 2:
                 now = self._prefill_pipelined(list(first.values()), now)
                 groups = rest
+        obs = self.observer
         for g in groups:
             runner = self.runners[g.model]
             t0 = time.perf_counter()  # cp: allow(CP006) real dispatch duration
-            runner.prefill_group(g)
+            with (obs.prefill_pass(g) if obs is not None else NO_SPAN):
+                runner.prefill_group(g)
             dt = self._clocked("prefill", t0)
             now += dt
-            if self.observer is not None:
-                self.observer.prefill(g.model, g.batch_size, dt)
+            if obs is not None:
+                obs.prefill(g.model, g.batch_size, dt, g.requests)
             for req in g.requests:
                 self._book_first_token(req, now)
         return now
@@ -1576,7 +1580,7 @@ class CrossPoolEngine:
             g = by_model[b.model]
             self.runners[b.model].apply_prefill_result(b, g)
             if self.observer is not None:
-                self.observer.prefill(g.model, g.batch_size, dt)
+                self.observer.prefill(g.model, g.batch_size, dt, g.requests)
             for req in g.requests:
                 self._book_first_token(req, now)
         return now

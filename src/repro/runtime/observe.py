@@ -13,6 +13,9 @@ Three layers, composable bottom-up (DESIGN.md §10):
   recorder.  Tracks (one per request, one per model, one for the engine
   step loop) map to tids; the clock is INJECTED so tests drive a fake
   monotonic clock and assert exact span sequences deterministically.
+  While a JAX profiler session records, the engine track's spans also
+  open ``cp.<span>`` host spans on the profiler's own clock, beside the
+  device ops (and their ``jax.named_scope`` stage scopes) they issue.
 * :class:`EngineObserver` — the facade the engine wires in.  It extends
   :class:`~repro.core.hooks.CoreHooks`, so attaching the SAME object to
   the virtualizer / arena / admission / rebalancer gives the core layer
@@ -28,12 +31,14 @@ from __future__ import annotations
 
 import bisect
 import collections
+import contextlib
 import json
 import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import SLOConfig
 from repro.core.hooks import CoreHooks
@@ -343,6 +348,15 @@ class MetricsRegistry:
 # span tracer (Chrome trace-event JSON, Perfetto-loadable)
 # ---------------------------------------------------------------------------
 
+#: A context manager that does nothing (a span nobody records).
+NO_SPAN = contextlib.nullcontext()
+
+#: Prefix of the host spans the engine opens on the profiler's clock
+#: (``jax.profiler.TraceAnnotation``: the event keeps its bare name, the
+#: keyword arguments become its stats).
+PROFILER_PREFIX = "cp."
+
+
 class SpanTracer:
     """Records begin/end/instant/complete events onto named tracks.
 
@@ -353,15 +367,26 @@ class SpanTracer:
     deterministic clock and real runs get wall time.  B/E events nest
     per track — callers keep per-track begin/end balanced (the engine's
     phase and request lifecycles are strictly bracketed).
+
+    The B/E spans of the ``profiled`` tracks are also opened and closed
+    as ``cp.<name>`` profiler spans while a JAX profiler session
+    records, so a device trace shows which engine phase issued each op
+    and in which phase the device sat idle.  With no session, a begin
+    costs one ``TraceAnnotation.is_enabled()`` call and builds nothing;
+    a span begun before a session started is not reported to it.
     """
 
     PID = 1
 
-    def __init__(self, clock: Callable[[], float] = time.perf_counter):
+    def __init__(self, clock: Callable[[], float] = time.perf_counter,
+                 profiled: Sequence[str] = ()):
         self._clock = clock
         self._t0 = clock()
         self.events: List[Dict] = []
         self._tids: Dict[str, int] = {}
+        self._profiled = frozenset(profiled)
+        # per profiled track: the open profiler spans, innermost last
+        self._annotations: Dict[str, List[Tuple[str, TraceAnnotation]]] = {}
 
     def now_us(self) -> float:
         return (self._clock() - self._t0) * 1e6
@@ -380,9 +405,16 @@ class SpanTracer:
         self.events.append({
             "name": name, "cat": cat, "ph": "B", "ts": self.now_us(),
             "pid": self.PID, "tid": self._tid(track), "args": args})
+        if track in self._profiled and TraceAnnotation.is_enabled():
+            span = TraceAnnotation(PROFILER_PREFIX + name, **args)
+            span.__enter__()
+            self._annotations.setdefault(track, []).append((name, span))
 
     def end(self, track: str, name: str, cat: str = "engine",
             **args) -> None:
+        open_spans = self._annotations.get(track)
+        if open_spans and open_spans[-1][0] == name:
+            open_spans.pop()[1].__exit__(None, None, None)
         self.events.append({
             "name": name, "cat": cat, "ph": "E", "ts": self.now_us(),
             "pid": self.PID, "tid": self._tid(track), "args": args})
@@ -458,7 +490,7 @@ class EngineObserver(CoreHooks):
     def __init__(self, clock: Callable[[], float] = time.perf_counter,
                  registry: Optional[MetricsRegistry] = None):
         self.metrics = registry if registry is not None else MetricsRegistry()
-        self.tracer = SpanTracer(clock=clock)
+        self.tracer = SpanTracer(clock=clock, profiled=(self.ENGINE_TRACK,))
         m = self.metrics
         # admission front door
         self.admission_total = m.counter(
@@ -616,11 +648,27 @@ class EngineObserver(CoreHooks):
         self._close(req)
         self._open(req, "admitted")
 
-    def prefill(self, model: str, batch_size: int, dt: float) -> None:
+    def prefill_pass(self, group):
+        """The ``cp.prefill_pass`` profiler span of one coalesced prompt
+        pass while a profiler session records (else :data:`NO_SPAN`):
+        model, rows, bucket and the request ids, space-separated."""
+        if not TraceAnnotation.is_enabled():
+            return NO_SPAN
+        return TraceAnnotation(
+            PROFILER_PREFIX + "prefill_pass", model=group.model,
+            rows=group.batch_size, bucket=group.bucket,
+            rids=" ".join(str(r.request_id) for r in group.requests))
+
+    def prefill(self, model: str, batch_size: int, dt: float,
+                requests: Sequence = ()) -> None:
+        """One prompt pass: its duration, and an X slice on the model's
+        track carrying the ids of the requests it served (``rids``), so a
+        request's first token joins the pass that made it."""
         self.prefill_seconds.labels(model).observe(dt)
         self.prefill_batch.labels().observe(batch_size)
         self.tracer.complete(f"model/{model}", "prefill", dt,
-                             cat="dispatch", batch=batch_size)
+                             cat="dispatch", batch=batch_size,
+                             rids=[r.request_id for r in requests])
 
     def first_token(self, req, ttft: float) -> None:
         """Prefill committed: the request's admitted span becomes its

@@ -508,3 +508,123 @@ def test_metrics_registry_counts_dropped_events():
     assert ctr is not None and ctr.labels("rebalance").value == 3
     # the log still holds the most recent events only
     assert [e["step"] for e in reg.recent_events("rebalance")] == [3, 4, 5, 6]
+
+
+# ---------------------------------------------------------------------------
+# engine spans on the profiler's clock (cp.<span> TraceAnnotations)
+# ---------------------------------------------------------------------------
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records every span
+    built, entered and exited."""
+
+    enabled = False
+    log: list = []
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+        self.log.append(("new", name))
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.enabled
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    import repro.core.pools as pools
+    import repro.runtime.observe as observe
+    _FakeAnnotation.log = []
+    _FakeAnnotation.enabled = False
+    monkeypatch.setattr(observe, "TraceAnnotation", _FakeAnnotation)
+    monkeypatch.setattr(pools, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation
+
+
+@pytest.mark.parametrize("lowering", [True, False])
+def test_profiler_off_builds_no_annotation(fake_profiler, lowering):
+    obs = EngineObserver(clock=FakeClock())
+    engine = _engine(names=(MOE, MLA), lowering=lowering, observer=obs)
+    engine.submit(Request(0, MOE, 6, 3, 0.0))
+    engine.submit(Request(1, MLA, 5, 3, 0.0))
+    engine.drain()
+    assert fake_profiler.log == []
+    assert ("B", "dispatch") in obs.tracer.span_names(
+        EngineObserver.ENGINE_TRACK)
+
+
+def test_profiler_on_engine_track_spans_nest(fake_profiler):
+    fake_profiler.enabled = True
+    tr = SpanTracer(clock=FakeClock(), profiled=("engine",))
+    tr.begin("engine", "step", step=1)
+    tr.begin("engine", "dispatch")
+    tr.begin("req/a#0", "decode")          # not a profiled track
+    tr.end("engine", "dispatch")
+    tr.end("req/a#0", "decode")
+    tr.end("engine", "step")
+    assert fake_profiler.log == [
+        ("new", "cp.step"), ("enter", "cp.step"),
+        ("new", "cp.dispatch"), ("enter", "cp.dispatch"),
+        ("exit", "cp.dispatch"), ("exit", "cp.step")]
+    # the in-memory events are the same with the profiler on or off
+    assert tr.span_names("engine") == [
+        ("B", "step"), ("B", "dispatch"), ("E", "dispatch"), ("E", "step")]
+
+
+def test_profiler_started_mid_span_closes_only_its_own(fake_profiler):
+    tr = SpanTracer(clock=FakeClock(), profiled=("engine",))
+    tr.begin("engine", "step")             # before the session
+    fake_profiler.enabled = True
+    tr.begin("engine", "commit")
+    tr.end("engine", "commit")
+    tr.end("engine", "step")               # never reported: nothing to close
+    assert fake_profiler.log == [("new", "cp.commit"), ("enter", "cp.commit"),
+                                 ("exit", "cp.commit")]
+
+
+def test_prefill_span_carries_request_ids():
+    obs = EngineObserver(clock=FakeClock())
+    engine = _engine(names=(MOE, MLA), observer=obs)
+    engine.submit(Request(0, MOE, 6, 2, 0.0))
+    engine.submit(Request(1, MOE, 7, 2, 0.0))
+    engine.submit(Request(2, MLA, 5, 2, 0.0))
+    engine.drain()
+    rids = [rid for track in (f"model/{MOE}", f"model/{MLA}")
+            for e in obs.tracer.track_events(track) if e["name"] == "prefill"
+            for rid in e["args"]["rids"]]
+    assert sorted(rids) == [0, 1, 2]
+
+
+def test_engine_step_writes_nested_cp_spans_into_the_xplane(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    engine = _engine(names=(MOE, MLA), observer=EngineObserver())
+    engine.submit(Request(0, MOE, 6, 4, 0.0))
+    engine.submit(Request(1, MLA, 5, 4, 0.0))
+    engine.step()                           # prompt passes, compiles
+    with jax.profiler.trace(str(tmp_path)):
+        engine.step()                       # one decode step, traced
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = {}
+    for plane in ProfileData.from_file(path[0]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("cp."):
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    (s0, s1, args), = spans["cp.step"]
+    assert "step" in args                   # metadata rides as stats
+    for inner in ("cp.dispatch", "cp.commit"):
+        (a, b, _), = spans[inner]
+        assert s0 <= a <= b <= s1
