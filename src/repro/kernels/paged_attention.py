@@ -11,10 +11,8 @@ Grid: ``(batch, page_blocks)``, page dimension sequential, online-softmax
 state in VMEM scratch across pages of one request.
 
 ``repro.kernels.ops.paged_kv_write`` is the matching write-side primitive:
-one XLA scatter that lands ``n`` token rows at their (page, slot)
-coordinates in the flat pool — jit- and donation-friendly, so the serving
-engine updates the pool buffer in place once per step instead of rebinding
-it per token.
+one window scatter that lands ``n`` token rows at their (page, slot)
+coordinates in the flat pool, in place on the donated buffer.
 """
 from __future__ import annotations
 

@@ -60,6 +60,14 @@ def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
 # Paged decode attention (page-table indirection, the virtualizer's view)
 # ---------------------------------------------------------------------------
 
+def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
+    """The table's pages: ``[B, max_pages, *page]`` from ``[N_pages, *page]``.
+
+    Unmapped entries (-1) read page 0; the lengths mask those tokens.
+    """
+    return pool[jnp.maximum(page_table, 0)]
+
+
 def paged_decode_attention(q: jax.Array, kv_pages: jax.Array,
                            page_table: jax.Array, lengths: jax.Array,
                            scale: float) -> jax.Array:
@@ -70,15 +78,22 @@ def paged_decode_attention(q: jax.Array, kv_pages: jax.Array,
     page_table: [B, max_pages] int32 physical page ids (-1 = unmapped)
     lengths:    [B] tokens valid per sequence
     """
-    B, _, H, D = q.shape
-    page_size = kv_pages.shape[1]
-    KV = kv_pages.shape[3]
-    max_pages = page_table.shape[1]
-    T = max_pages * page_size
-    safe = jnp.maximum(page_table, 0)
-    gathered = kv_pages[safe]                       # [B,max_pages,ps,2,KV,D]
-    k = gathered[:, :, :, 0].reshape(B, T, KV, D)
-    v = gathered[:, :, :, 1].reshape(B, T, KV, D)
+    return gathered_decode_attention(q, gather_pages(kv_pages, page_table),
+                                     lengths, scale)
+
+
+def gathered_decode_attention(q: jax.Array, kv: jax.Array,
+                              lengths: jax.Array, scale: float) -> jax.Array:
+    """Decode attention over each request's pages, already gathered.
+
+    q:       [B,1,H,D]
+    kv:      [B, max_pages, page_size, 2, KV, D]
+    lengths: [B] tokens valid per sequence
+    """
+    B, P, page_size, _, KV, D = kv.shape
+    T = P * page_size
+    k = kv[:, :, :, 0].reshape(B, T, KV, D)
+    v = kv[:, :, :, 1].reshape(B, T, KV, D)
     return decode_attention(q, k, v, lengths, scale)
 
 
@@ -93,12 +108,23 @@ def paged_mla_decode_attention(q: jax.Array, kv_pages: jax.Array,
     lengths:    [B] tokens valid per sequence
     Returns the latent context [B,1,H,latent_dim].
     """
-    B, _, H, e = q.shape
-    page_size = kv_pages.shape[1]
-    max_pages = page_table.shape[1]
-    T = max_pages * page_size
-    safe = jnp.maximum(page_table, 0)
-    rows = kv_pages[safe].reshape(B, T, e)          # [B,T, r+rp]
+    return gathered_mla_decode_attention(
+        q, gather_pages(kv_pages, page_table), lengths, latent_dim, scale)
+
+
+def gathered_mla_decode_attention(q: jax.Array, kv: jax.Array,
+                                  lengths: jax.Array, latent_dim: int,
+                                  scale: float) -> jax.Array:
+    """Absorbed-MLA decode attention over each request's gathered pages.
+
+    q:       [B,1,H, r+rp]
+    kv:      [B, max_pages, page_size, r+rp]
+    lengths: [B] tokens valid per sequence
+    Returns the latent context [B,1,H,latent_dim].
+    """
+    B, P, page_size, e = kv.shape
+    T = P * page_size
+    rows = kv.reshape(B, T, e)                      # [B,T, r+rp]
     scores = jnp.einsum("bshe,bte->bhst", q, rows,
                         preferred_element_type=jnp.float32) * scale
     mask = (jnp.arange(T)[None, :] < lengths[:, None])[:, None, None, :]

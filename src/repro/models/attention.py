@@ -4,8 +4,11 @@ Each variant provides ``init_*`` (params), ``*_full`` (whole-sequence, used
 by train/prefill) and ``*_decode`` (single-token against a cache).  The
 decode cache layouts are exactly what the CrossPool KV-cache pool manages.
 The paged decodes name their pool work for the profiler: ``kv_write`` (the
-new token's scatter), ``kv_view`` (the typed view of the pool) and
-``attention`` (the paged score/context kernel).
+new token's window write into the pool), ``kv_view`` (the typing of the
+pages the step gathered, ``kernels/ops._typed_pages``) and ``attention``
+(the gather of the table's pages and the score/context kernel).  Neither
+touches the whole ``[page_budget, page_elems]`` pool: the write moves the
+new rows, the gather whole page rows.
 """
 from __future__ import annotations
 
@@ -289,14 +292,10 @@ def gqa_paged_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
     with jax.named_scope("kv_write"):
         pool = kops.paged_kv_write(pool, kv_tok, page,
                                    lengths % tokens_per_page)
-    n_pages = pool.shape[0]
-    with jax.named_scope("kv_view"):
-        typed = pool[:, : tokens_per_page * per_tok].reshape(
-            n_pages, tokens_per_page, 2, KV, hd)
-    with jax.named_scope("attention"):
-        out = kops.paged_decode_attention(q, typed, page_table, lengths + 1,
-                                          scale=cfg.head_dim ** -0.5,
-                                          impl=impl)
+    out = kops.paged_decode_attention(
+        q, pool, page_table, lengths + 1,
+        page_shape=(tokens_per_page, 2, KV, hd), scale=cfg.head_dim ** -0.5,
+        impl=impl)
     out = out.reshape(B, 1, cfg.n_heads * cfg.head_dim).astype(x.dtype)
     return hooks.attn_out(out @ p["wo"]), pool
 
@@ -327,19 +326,15 @@ def mla_paged_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
     with jax.named_scope("kv_write"):
         pool = kops.paged_kv_write(pool, kv_tok, page,
                                    lengths % tokens_per_page)
-    n_pages = pool.shape[0]
-    with jax.named_scope("kv_view"):
-        typed = pool[:, : tokens_per_page * per_tok].reshape(
-            n_pages, tokens_per_page, per_tok)
     # absorb W_uk into q; score against [latent | rope] rows directly
     wuk = p["wuk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, wuk)
     q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)      # [B,1,H,r+rp]
     scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    with jax.named_scope("attention"):
-        ctx = kops.paged_mla_decode_attention(
-            q_cat, typed, page_table, lengths + 1,
-            latent_dim=m.kv_lora_rank, scale=scale, impl=impl)
+    ctx = kops.paged_mla_decode_attention(
+        q_cat, pool, page_table, lengths + 1,
+        page_shape=(tokens_per_page, per_tok), latent_dim=m.kv_lora_rank,
+        scale=scale, impl=impl)
     wuv = p["wuv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
     out = jnp.einsum("bshr,rhv->bshv", ctx, wuv)
     out = out.reshape(B, 1, H * m.v_head_dim).astype(x.dtype)

@@ -163,6 +163,101 @@ def test_paged_equals_contiguous():
 
 
 # ---------------------------------------------------------------------------
+# paged KV write and the gather-first paged decode (kernels/ops.py)
+# ---------------------------------------------------------------------------
+
+PAGE_ELEMS = 32 * 1024                   # a 64 KiB bf16 page
+
+
+def _element_scatter(pool, kv, pages, slots):
+    """The element scatter ``ops.paged_kv_write`` replaced: one index per
+    element, unmapped rows sent out of range and dropped."""
+    e = kv.shape[-1]
+    rows = jnp.where(pages >= 0, pages, pool.shape[0])
+    cols = (slots * e)[:, None] + jnp.arange(e)[None, :]
+    return pool.at[rows[:, None], cols].set(kv.astype(pool.dtype),
+                                             mode="drop")
+
+
+def _prompt_rows(e, n, table):
+    """Pages and slots of a prompt's first ``n`` tokens: rows share pages."""
+    tpp = PAGE_ELEMS // e
+    toks = np.arange(n)
+    return (np.asarray(table, np.int32)[toks // tpp],
+            (toks % tpp).astype(np.int32))
+
+
+@pytest.mark.parametrize("e,pages,slots", [
+    (4096, [3, 0, 5, 2], [1, 7, 0, 4]),              # decode, distinct pages
+    (288, *_prompt_rows(288, 230, [4, 1, 6])),       # a prompt sharing pages
+    (4096, [2, -1, 4, -1], [3, 3, 0, 6]),            # unmapped rows dropped
+    (288, [1, 3, -1], [112, 112, 112]),              # MLA slot 112: to 32544
+    (4096, [5, 0], [7, 7]),                          # a GQA page's last slot
+], ids=["decode", "prompt", "unmapped", "mla-last-slot", "gqa-last-slot"])
+def test_paged_kv_write_matches_element_scatter(e, pages, slots):
+    """The window scatter writes the same bf16 bits to the same slots as
+    the element scatter, and leaves every other element as it was."""
+    from repro.kernels import ops as kops
+    ks = jax.random.split(jax.random.PRNGKey(e + len(pages)), 2)
+    pool = _rand(ks[0], (7, PAGE_ELEMS), jnp.bfloat16)
+    kv = _rand(ks[1], (len(pages), e), jnp.float32)
+    pages = jnp.asarray(pages, jnp.int32)
+    slots = jnp.asarray(slots, jnp.int32)
+    want = _element_scatter(pool, kv, pages, slots)
+    got = jax.jit(kops.paged_kv_write)(pool, kv, pages, slots)
+    assert got.dtype == pool.dtype
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint16),
+                                  np.asarray(want).view(np.uint16))
+    assert not np.array_equal(np.asarray(got), np.asarray(pool))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_gather_first_paged_decode_matches_whole_pool_view(kind, impl):
+    """Gathering the table's pages from the untyped pool and typing only
+    that block gives the same attention as typing the whole pool and
+    gathering through the table, with unmapped entries and mixed lengths
+    (bit for bit); the Pallas kernels, which take the whole pool typed,
+    agree to their tolerance."""
+    from repro.kernels import ops as kops
+    B, H, page_elems, n_pages, max_pages = 3, 4, 1024, 11, 4
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+    pool = _rand(ks[0], (n_pages, page_elems))
+    if kind == "gqa":
+        KV, D = 2, 16
+        page_shape = (page_elems // (2 * KV * D), 2, KV, D)
+        q = _rand(ks[1], (B, 1, H, D))
+    else:
+        r, rp = 16, 8                    # 42 tokens a page, 16 elems slack
+        page_shape = (page_elems // (r + rp), r + rp)
+        q = _rand(ks[1], (B, 1, H, r + rp))
+    ps = page_shape[0]
+    table = jnp.asarray([[7, 2, -1, -1], [0, 9, 4, 10], [5, -1, -1, -1]],
+                        jnp.int32)
+    lengths = jnp.asarray([ps + 3, 4 * ps, 1], jnp.int32)
+    n = int(np.prod(page_shape))
+    typed = pool[:, :n].reshape(n_pages, *page_shape)
+    if kind == "gqa":
+        want = ref.paged_decode_attention(q, typed, table, lengths, D ** -0.5)
+        got = kops.paged_decode_attention(q, pool, table, lengths,
+                                          page_shape=page_shape,
+                                          scale=D ** -0.5, impl=impl)
+    else:
+        want = ref.paged_mla_decode_attention(q, typed, table, lengths, r,
+                                              (r + rp) ** -0.5)
+        got = kops.paged_mla_decode_attention(q, pool, table, lengths,
+                                              page_shape=page_shape,
+                                              latent_dim=r,
+                                              scale=(r + rp) ** -0.5,
+                                              impl=impl)
+    if impl == "xla":
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
 # grouped expert GEMM
 # ---------------------------------------------------------------------------
 

@@ -10,7 +10,9 @@ process at a time may load the TPU library, and every test worker imports
 this file.  The persistent compilation cache is off around the compiles
 (an entry written for a described chip cannot be read back without one).
 """
+import math
 import os
+import re
 import time
 
 import jax
@@ -53,14 +55,14 @@ def _on(sharding, tree):
         tree)
 
 
-def _one_layer(name, sharding):
+def _one_layer(name, sharding, page_budget=B * MAX_PAGES):
     """Stage fns + sharded shapes of ONE layer at published widths."""
     cfg = get_config(name).replace(n_layers=1)
     kv_shapes, w_shapes = split_exec.split_params(
         jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0)), cfg)
     view = weight_pool.build_view(name, cfg, w_shapes,
                                   slab_bytes=weight_pool.DEFAULT_SLAB_BYTES)
-    virt = KVVirtualizer({name: cfg}, page_budget=B * MAX_PAGES,
+    virt = KVVirtualizer({name: cfg}, page_budget=page_budget,
                          page_bytes=PAGE_BYTES, allocate_device_pool=False)
     fns = split_exec.make_stage_fns(cfg, virt.views[name], view)
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
@@ -102,12 +104,61 @@ def test_attn_stage_compiles(name, one_chip):
     jax.jit(fns.attn_stage, donate_argnums=2).lower(*args).compile()
 
 
-def test_paged_kv_write_compiles(one_chip):
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    n, e = 512, 2 * 16 * 128                 # a moonshot prompt, one layer
-    args = (S((16384, PAGE_BYTES // 2), jnp.bfloat16),
+#: The served pool: 16384 pages of 64 KiB, 1 GiB of bf16.
+POOL_PAGES = 16384
+#: Instructions that copy or relayout what they produce (``copy-done``
+#: ends an asynchronous copy).
+_RELAYOUT = re.compile(r"= \w+\[([\d,]*)\]\S* "
+                       r"(copy|copy-done|reshape|slice|transpose|fusion"
+                       r"|scatter)\(")
+
+
+def _whole_pool_relayouts(text: str, e: int) -> list:
+    """Instructions of a compiled program that copy, relayout or scatter a
+    result as large as the pool's typed tokens (every page's ``e``-element
+    rows): the pool's own in-place update (``dynamic-update-slice``) and
+    the buffer passed through (parameters, tuples) are not counted."""
+    typed = POOL_PAGES * (PAGE_BYTES // 2 // e) * e
+    return [line.strip()[:160] for line in text.splitlines()
+            if (m := _RELAYOUT.search(line))
+            and math.prod(int(d) for d in m.group(1).split(",") if d)
+            >= typed]
+
+
+def _attn_stage_1gib(name, sharding):
+    """One layer's decode attention stage over the 1 GiB pool, B = 16."""
+    cfg, fns, _, kv_params, pool, S = _one_layer(name, sharding, POOL_PAGES)
+    args = (kv_params, S((16, 1, cfg.d_model), jnp.bfloat16), pool,
+            S((1, 16, MAX_PAGES), jnp.int32), S((16,), jnp.int32),
+            S((), jnp.int32))
+    e = (cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
+         if cfg.attention == "mla" else 2 * cfg.n_kv_heads * cfg.head_dim)
+    return jax.jit(fns.attn_stage, donate_argnums=2), args, e
+
+
+def _kv_write(n, e, sharding):
+    """``paged_kv_write`` of ``n`` rows of ``e`` elements into the pool."""
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    args = (S((POOL_PAGES, PAGE_BYTES // 2), jnp.bfloat16),
             S((n, e), jnp.bfloat16), S((n,), jnp.int32), S((n,), jnp.int32))
-    jax.jit(paged_kv_write, donate_argnums=0).lower(*args).compile()
+    return jax.jit(paged_kv_write, donate_argnums=0), args, e
+
+
+@pytest.mark.parametrize("case", [
+    lambda sh: _attn_stage_1gib(MOONSHOT, sh),
+    lambda sh: _attn_stage_1gib(MINICPM, sh),
+    lambda sh: _kv_write(16, 288, sh),          # a decode step's rows (MLA)
+    lambda sh: _kv_write(512, 2 * 16 * 128, sh),  # a moonshot prompt's layer
+], ids=["attn-stage-moonshot", "attn-stage-minicpm3", "kv-write-b16",
+        "kv-write-n512"])
+def test_pool_is_never_relayouted(case, one_chip):
+    """At the served 1 GiB pool, the decode attention stage and the KV
+    write compile with no copy, reshape, slice, transpose, fusion or
+    scatter of the whole pool: the write updates it in place and the
+    attention types only the pages it gathered."""
+    fn, args, e = case(one_chip)
+    text = fn.lower(*args).compile().as_text()
+    assert _whole_pool_relayouts(text, e) == []
 
 
 @pytest.mark.parametrize("page_size", [8, 32])
