@@ -1,19 +1,20 @@
 """Decide ``correct``: served tokens against the plain reference.
 
 After the window, a sample of finished requests per model, drawn from the
-seed and always holding the longest, goes through the reference once each
-(prompt plus served tokens, teacher-forced).  Each served token's gap is
-how far its reference logit lies below the reference's best at its
-position, in units of that position's logit standard deviation.  Every
-token is greedy, so a served token is the program's own first choice
-there.  A model's ``checks`` (configuration file) name the numbers taken
-over those gaps (:func:`statistic`) and the limit of each; :func:`judge`
-decides ``correct`` from them.
+seed and always holding the longest, goes through the model's plain
+reference (the module its entry names under ``reference``, default
+``cb.reference``) once each (prompt plus served tokens, teacher-forced).
+Each served token's gap is how far its reference logit lies below the
+reference's best at its position, in units of that position's logit
+standard deviation.  Every token is greedy, so a served token is the
+program's own first choice there.  A model's ``checks`` (configuration
+file) name the numbers taken over those gaps (:func:`statistic`) and the
+limit of each; :func:`judge` decides ``correct`` from them.
 
-A control (``cb.reference.QUANTIZERS``) is read at the same positions: the
-gap of the token the lower-precision reference puts first there.  Judged
-by the same :func:`judge` in the program's place, it has to come out as
-not correct.
+A control (one of the reference module's ``QUANTIZERS``) is read at the
+same positions: the gap of the token the lower-precision reference puts
+first there.  Judged by the same :func:`judge` in the program's place, it
+has to come out as not correct.
 """
 from __future__ import annotations
 
@@ -21,8 +22,6 @@ import gc
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
-
-from cb.reference import Reference, served_gaps
 
 
 def sample_requests(reqs, models, per_model: int, seed: int
@@ -68,22 +67,27 @@ def compare(cfg: Dict, repo_models, samples: Dict[str, List], seed: int,
     and under ``controls[mode]`` the gap of each control's own first choice
     at the same positions."""
     import jax
-    from cb.setup import model_keys, seeded_params
+    from cb.setup import model_keys, reference_module, seeded_params
     keys = model_keys(cfg, seed)
     max_ctx = int(cfg["engine"]["max_ctx"])
     out = {}
     for name, reqs in samples.items():
         if not reqs:
             continue
+        mod = reference_module(cfg["models"][name])
+        unknown = set(controls) - set(mod.QUANTIZERS)
+        if unknown:
+            raise ValueError(f"{name}: {mod.__name__} has no control "
+                             f"{sorted(unknown)}")
         m = cfg["models"][name]["as_run"]
         params = seeded_params(repo_models[name], keys[name],
                                jax.devices()[0])
-        ref = Reference(m, max_ctx, max_ctx, "f32")
-        ctls = [Reference(m, max_ctx, max_ctx, c) for c in controls]
+        ref = mod.Reference(m, max_ctx, max_ctx, "f32")
+        ctls = [mod.Reference(m, max_ctx, max_ctx, c) for c in controls]
         rd = out[name] = {"requests": len(reqs), "token_gaps": [],
                           "controls": {c: [] for c in controls}}
         for r in reqs:
-            g = served_gaps(ref, params, r.prompt, r.tokens, ctls)
+            g = mod.served_gaps(ref, params, r.prompt, r.tokens, ctls)
             rd["token_gaps"].append(g[:, 0])
             for j, c in enumerate(controls):
                 rd["controls"][c].append(g[:, 1 + j])

@@ -229,7 +229,12 @@ class Load:
                     (view.n_layers, view.slabs_per_layer), jnp.int32,
                     sharding=None if arena.device is None else
                     jax.sharding.SingleDeviceSharding(arena.device))
-                arenas[m] = (arena.arena, table)
+                # the FFN's input crosses to the arena's chip where the
+                # arena has one of its own (the host-driven pipeline)
+                ffn_dev = dev if arena.device is None or \
+                    arena.device in dev.device_set else \
+                    jax.sharding.SingleDeviceSharding(arena.device)
+                arenas[m] = (arena.arena, table, ffn_dev)
         except (ImportError, AttributeError, KeyError) as e:
             log(f"[warm-up] nothing compiled ahead: {e!r}")
             return 0
@@ -254,12 +259,14 @@ class Load:
                 mla=mc.attention == "mla")
 
         def stage_jobs(m, S, B):
-            sp, (arena, slots) = runners[m], arenas[m]
+            sp, (arena, slots, ffn_dev) = runners[m], arenas[m]
             p_kv = sp.pooled.kv_params
             tok = jax.ShapeDtypeStruct((B, S), jnp.int32)
             layer = jax.ShapeDtypeStruct((), jnp.int32, weak_type=True)
             x = on_dev(jax.eval_shape(sp._embed, p_kv, tok))
-            ffn_in = on_dev(jax.eval_shape(sp._attn, p_kv, x, layer)[1])
+            ffn_in = jax.eval_shape(sp._attn, p_kv, x, layer)[1]
+            ffn_in = jax.ShapeDtypeStruct(ffn_in.shape, ffn_in.dtype,
+                                          sharding=ffn_dev)
             ffn_out = on_dev(jax.eval_shape(sp._ffn, arena, slots, ffn_in,
                                             layer))
             idx = jax.ShapeDtypeStruct(() if B == 1 else (B,), jnp.int32)

@@ -34,6 +34,25 @@ below the bfloat16 the configuration states, each scaled to its range
 subnormals below 2**-6); and the float32 router's product at
 ``precision=HIGH`` (three bf16 passes), the step below the ``highest`` it
 is stated at.
+
+The interface of a reference module.  A model's entry in a configuration
+file names its reference under ``reference`` (a module under
+``chipbench/cb/``; this one where it names none), which provides:
+
+* ``Reference(as_run, length, max_ctx, mode)``: the model that one
+  ``as_run`` block describes, over requests padded to ``length`` tokens,
+  a prompt running as the engine runs it under ``max_ctx``; ``mode`` is
+  ``"f32"`` or a control.  ``hidden(params, ids, plen)`` gives the float32
+  hidden state after the final norm, ``[length, d]``, of ``ids`` whose
+  first ``plen`` are the prompt; ``argmax(params, x)`` each position's
+  first token under ``mode``, ``[length]``; ``gaps(params, x, targets)``
+  each target token's gap in float32, ``[length, T]``.  ``params`` are the
+  benchmark's seeded weights in the engine's layout.
+* ``served_gaps(ref, params, prompt, served, controls)``: the gaps at the
+  served tokens' positions, ``[n, 1 + len(controls)]`` (below).
+* ``QUANTIZERS``: the control modes, each a name and its rounding.
+* ``KEYS``: the ``as_run`` keys it computes; the harness refuses a model
+  with a key outside them before anything is built.
 """
 from __future__ import annotations
 
@@ -88,6 +107,14 @@ def _q_fp8(a: jax.Array, axis: int) -> jax.Array:
 
 
 QUANTIZERS = {"int8": _q_int8, "fp8": _q_fp8}
+
+#: The ``as_run`` keys this reference computes (a width it takes from the
+#: weights, such as ``d_model`` or ``vocab``, is among them).
+KEYS = frozenset({
+    "n_layers", "d_model", "vocab", "attention", "n_heads", "n_kv_heads",
+    "head_dim", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+    "qk_rope_head_dim", "v_head_dim", "ffn", "d_ff", "n_experts",
+    "experts_per_token", "capacity_factor", "rope_theta", "norm_eps"})
 
 
 def mm(x: jax.Array, w: jax.Array, mode: str,

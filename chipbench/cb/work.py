@@ -26,6 +26,9 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Sequence, Tuple
 
+#: ``as_run`` keys that change no byte or operation of a decode step.
+WORK_NEUTRAL = frozenset({"rope_theta", "norm_eps", "capacity_factor"})
+
 
 @dataclass(frozen=True)
 class ModelShape:
@@ -54,9 +57,15 @@ class ModelShape:
 
     @classmethod
     def from_dict(cls, name: str, d: Dict) -> "ModelShape":
-        keys = cls.__dataclass_fields__
-        return cls(name=name, **{k: v for k, v in d.items()
-                                 if k in keys and k != "name"})
+        """The shape of one ``as_run`` block; a key that is neither counted
+        nor in :data:`WORK_NEUTRAL` is refused, so no count ignores part of
+        the model."""
+        keys = set(cls.__dataclass_fields__) - {"name"}
+        unknown = sorted(set(d) - keys - WORK_NEUTRAL)
+        if unknown:
+            raise ValueError(f"{name}: cb.work.ModelShape does not count "
+                             f"{unknown}")
+        return cls(name=name, **{k: v for k, v in d.items() if k in keys})
 
     # ---- per-layer parameter counts --------------------------------------
     def attn_params(self) -> int:

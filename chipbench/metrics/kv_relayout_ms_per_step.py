@@ -1,6 +1,6 @@
 """Device self time under the ``kv_write`` and ``kv_view`` scopes (the
-new token's write into the pool and the typed views of the whole pool)
-per traced decode-only step, in ms (``cb.scopes``)."""
+new token's write into the pool and the typing of the pages a request's
+table gathers) per traced decode-only step, in ms (``cb.scopes``)."""
 from cb.scopes import RELAYOUT, decode_only_scopes, seconds_under
 
 

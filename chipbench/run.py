@@ -16,7 +16,8 @@ of the window's first seconds, the engine's counters and the harness's
 host spans.  Each metric is computed by ``chipbench/metrics/<name>.py``.
 
 After the window the engine is freed and a sample of the finished
-requests goes through the plain float32 reference (``cb.reference``);
+requests goes through each model's plain float32 reference (the module its
+entry names, ``cb.reference`` by default);
 ``correct`` says whether each number the configuration compares over the
 served tokens' gaps stays within its limit (``cb.check.judge``).  Without
 a TPU, or with fewer chips than the cell asks for, the run exits non-zero
@@ -168,6 +169,11 @@ def run_cell(args, *, require_tpu: bool = True, root: Path = ROOT,
     from cb.traffic import Traffic
 
     cfg = setup.load_config(root / conf["file"])
+    # a model that its work count or its reference does not describe is
+    # refused before anything is built (the program's side: build_engine)
+    shapes = setup.shapes(cfg)
+    for m in cfg["models"].values():
+        setup.reference_module(m)
     # the precision the configuration states for the program's float32
     # products (on a TPU an unmarked float32 product runs in one bf16 pass)
     jax.config.update("jax_default_matmul_precision",
@@ -251,6 +257,11 @@ def run_cell(args, *, require_tpu: bool = True, root: Path = ROOT,
     late = [r.submitted - r.due for r in drv.reqs.values()
             if r.phase == "window" and r.submitted is not None]
 
+    # the chips the engine's work runs on: the KV pool's, and under the
+    # host-driven pipeline (lowering off) the weights arena's too
+    chips = list(dict.fromkeys(
+        [engine.kv_device] if cfg["engine"]["lowering"]
+        else [engine.kv_device, engine.w_device]))
     mem_peak = 0
     for d in jax.local_devices():
         st = d.memory_stats() or {}
@@ -263,6 +274,13 @@ def run_cell(args, *, require_tpu: bool = True, root: Path = ROOT,
         if path is not None:
             raw = load_xplane(path)
             reduced = reduce_trace(raw)
+            if reduced is not None:
+                # the first chip is the KV pool's, which reduced reads
+                busy = [reduced["busy_s"]] + [
+                    (reduce_trace(raw, device=f"/device:TPU:{d.id}")
+                     or {"busy_s": 0.0})["busy_s"] for d in chips[1:]]
+                log(f"[trace] busy seconds by chip used {busy}")
+                reduced["busy_mean_s"] = sum(busy) / len(busy)
             traced = [s for s in drv.steps if trace_state.get(
                 "open", np.inf) <= s.t0 < trace_state["closed"]]
             if reduced is not None and len(traced) == len(reduced["steps"]):
@@ -277,12 +295,11 @@ def run_cell(args, *, require_tpu: bool = True, root: Path = ROOT,
                     json.dumps(trim(raw)))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
-    shapes = setup.shapes(cfg)
     run = {"requests": list(drv.reqs.values()), "steps": drv.steps,
            "window": (t_w0, t_w1), "window_open_until": t_open_until,
            "seconds": args.seconds, "setup_s": setup_s, "shapes": shapes,
            "peaks": peaks, "registry": registry, "trace": reduced,
-           "chips_used": 1 if cfg["engine"]["lowering"] else 2}
+           "chips_used": len(chips)}
     metrics = {}
     for m in cell_metrics(bench, cell["name"], bool(args.trace)):
         v = load_reader(m["name"])(run)
@@ -352,7 +369,7 @@ def run_cell(args, *, require_tpu: bool = True, root: Path = ROOT,
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": mem_peak}
     if reduced is not None:
-        device["busy_s"] = reduced["busy_s"]
+        device["busy_s"] = reduced["busy_mean_s"]
         device["window_s"] = reduced["window_s"]
     out = {"correct": bool(correct), "attempted": attempted,
            "failed": failed, "metrics": metrics, "device": device}
